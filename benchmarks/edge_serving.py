@@ -28,14 +28,8 @@ for one substrate, in two modes per worker count:
   hide one behind the other. Every row is checked bit-identical to the
   single-worker host reference.
 
-The worker sweep runs in a child process with
-``jax_cpu_enable_async_dispatch=False`` (the flag is only read when the
-CPU client is created, so it cannot be toggled mid-process): XLA:CPU's
-default async dispatch funnels every execution through one dispatch
-thread, which would serialize concurrent batches — an artifact of the
-host backend, not of the serving design. With synchronous dispatch each
-execution runs on its worker thread, matching how concurrent batches
-occupy a real accelerator.
+The worker sweep runs in the same process as the settings sweep: one
+process holds the device, so no child process ever needs it.
 
 Standalone:  PYTHONPATH=src python benchmarks/edge_serving.py [--dry-run]
              [--substrates exact,approx_lut] [--requests 32]
@@ -46,10 +40,7 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import pathlib
-import subprocess
-import sys
 
 import jax
 import numpy as np
@@ -162,41 +153,8 @@ def worker_sweep(spec: str, imgs, workers=WORKER_COUNTS) -> dict:
         "max_batch": WORKER_SWEEP_BATCH,
         "requests": len(imgs),
         "emulated_device_latency_ms": round(cal_s * 1e3, 3),
-        "cpu_sync_dispatch": not jax.config._read(
-            "jax_cpu_enable_async_dispatch"),
         "rows": out_rows,
     }
-
-
-def _worker_sweep_subprocess(spec: str, n_requests: int,
-                             dry_run: bool) -> dict:
-    """Run :func:`worker_sweep` in a child process.
-
-    ``jax_cpu_enable_async_dispatch`` is read once, when the CPU client is
-    created — by the time the settings sweep has run it can no longer be
-    turned off in this process, so the sweep gets a fresh interpreter that
-    sets the flag first (see the module docstring for why it must be off).
-    """
-    cmd = [sys.executable, str(pathlib.Path(__file__).resolve()),
-           "--worker-sweep-only", spec, "--requests", str(n_requests)]
-    if dry_run:
-        cmd.append("--dry-run")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = str(_REPO_ROOT / "src") + os.pathsep + \
-        env.get("PYTHONPATH", "")
-    proc = subprocess.run(cmd, capture_output=True, text=True, env=env)
-    marker = "WORKER_SWEEP_JSON:"
-    payload = None
-    for line in proc.stdout.splitlines():
-        if line.startswith(marker):
-            payload = json.loads(line[len(marker):])
-        else:
-            print(line)
-    if proc.returncode != 0 or payload is None:
-        raise RuntimeError(
-            f"worker sweep subprocess failed (rc={proc.returncode}):\n"
-            f"{proc.stdout}\n{proc.stderr}")
-    return payload
 
 
 def run(substrates=None, dry_run: bool = False, n_requests: int = 32,
@@ -249,9 +207,8 @@ def run(substrates=None, dry_run: bool = False, n_requests: int = 32,
                 })
 
         # throughput-vs-worker-count table on the paper's served substrate
-        # (child process: needs jax_cpu_enable_async_dispatch=False)
         sweep_spec = "approx_lut" if "approx_lut" in specs else specs[0]
-        sweep = _worker_sweep_subprocess(sweep_spec, n_requests, dry_run)
+        sweep = worker_sweep(sweep_spec, list(imgs), workers=worker_counts)
         for row in sweep["rows"]:
             rows.append((
                 f"serve_edge/{sweep_spec}/workers{row['workers']}"
@@ -295,22 +252,7 @@ def main() -> None:
                     help="output path for BENCH_serving.json ('' disables)")
     ap.add_argument("--trace", default=None, dest="trace_path",
                     help="write a Chrome/Perfetto trace of the serving spans")
-    ap.add_argument("--worker-sweep-only", default=None, metavar="SPEC",
-                    help="internal: run only the worker sweep for SPEC and "
-                         "print its JSON record (spawned as a subprocess so "
-                         "the CPU client is created with synchronous "
-                         "dispatch)")
     args = ap.parse_args()
-    if args.worker_sweep_only:
-        # must happen before the first computation creates the CPU client
-        jax.config.update("jax_cpu_enable_async_dispatch", False)
-        n = 6 if args.dry_run else args.requests
-        counts = (1, 2) if args.dry_run else WORKER_COUNTS
-        imgs = image_batch(n, 32, 32, noise=1.5)
-        record = worker_sweep(args.worker_sweep_only, list(imgs),
-                              workers=counts)
-        print("WORKER_SWEEP_JSON:" + json.dumps(record))
-        return
     substrates = args.substrates.split(",") if args.substrates else None
     rows = run(substrates=substrates, dry_run=args.dry_run,
                n_requests=args.requests, json_path=args.json_path or None,

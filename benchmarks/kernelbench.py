@@ -193,7 +193,7 @@ def _run_benches(substrates, sharded, dry_run, json_path) -> list:
 
     # pallas × wiring × width sweep: every CSP wiring rides the generated
     # closed-form kernel (cost_hint "vpu"); only product models without CSP
-    # structure ("exact") fall back to the flat-table gather ("gather").
+    # structure ("exact") fall back to the LUT kernel ("mxu").
     pm = pk = pn = 32 if dry_run else 128
     pa = jnp.asarray(rng.integers(-128, 128, (pm, pk)), jnp.int8)
     pb = jnp.asarray(rng.integers(-128, 128, (pk, pn)), jnp.int8)

@@ -37,6 +37,7 @@ from benchmarks import (
     table4_errors,
     table5_hardware,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = {
     "table2": table2_compressors,
@@ -69,6 +70,7 @@ def main() -> None:
                     help="substrate-plan JSON or bundle dir for the "
                          "autotune bench (default: greedy search)")
     args = ap.parse_args()
+    enable_compile_cache()
     substrates = args.substrates.split(",") if args.substrates else None
 
     rows = []

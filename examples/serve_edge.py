@@ -29,6 +29,7 @@ import argparse
 import numpy as np
 
 from repro.data import mixed_shape_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.nn import conv
 from repro.obs import (ContractionMeter, MetricsRegistry, Tracer,
                        telemetry_scope, tracing_scope, write_chrome_trace,
@@ -60,6 +61,7 @@ def main():
                     help="write a Chrome/Perfetto trace-event JSON of the "
                          "serving spans")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         args.requests = 6

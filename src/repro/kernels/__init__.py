@@ -13,8 +13,9 @@ bit-identity test sweeps).
   kernels over a pluggable closed-form product model (vectorized
   ``k_chunk`` k-slab walk).
 * ``lut_matmul`` — matmul fallback for product models with no CSP
-  structure: the scalar product is a gather into a flat (2^N · 2^N,)
-  product table, enumerable at widths 3..8.
+  structure: the scalar product is an entry of the (2^N, 2^N) product
+  table, selected by int8 one-hot matmuls on the MXU (Mosaic gathers only
+  within one vreg); enumerable widths 3..8.
 * ``fused_conv`` — batched 'same' conv with im2col *inside* the kernel
   (row-shifted padded views, per-distinct-coefficient product maps); the
   fast path behind ``nn.conv.conv2d_batched`` for Pallas substrates.

@@ -11,11 +11,13 @@ Tiling: grid (M/bm, N/bn, K/bk); the output block (bm, bn) is revisited
 across the k dimension (TPU sequential grid) and accumulated in place. The
 inner k-slab is walked in ``k_chunk``-wide vectorized slabs: each step
 broadcasts a (bm, kc, 1) slice of A against a (1, kc, bn) slice of B and
-reduces the kc axis — one whole-slab VPU evaluation instead of the
-historical per-k rank-1 ``fori_loop`` (recoverable with ``k_chunk=1``,
-which benchmarks keep as the baseline). The (bm, kc, bn) int32 working set
-bounds VMEM: 512 KiB at the default 128×8×128 — a full 128-deep slab would
-need 8 MiB, which is why the chunk walk exists.
+reduces the kc axis — one whole-slab VPU evaluation instead of a per-k
+rank-1 update (recoverable with ``k_chunk=1``, which benchmarks keep as the
+baseline). The slab walk is a Python loop with static offsets: Mosaic
+lowers static value slices, but neither ``dynamic_slice`` on loaded values
+nor a dynamic ref slice that is not a multiple of 128 lanes. The (bm, kc,
+bn) int32 working set bounds VMEM: 512 KiB at the default 128×8×128 — a
+full 128-deep slab would need 8 MiB, which is why the chunk walk exists.
 """
 from __future__ import annotations
 
@@ -35,8 +37,7 @@ def resolve_k_chunk(k_chunk: int, block_k: int) -> int:
     return max(1, math.gcd(int(k_chunk), int(block_k)))
 
 
-def _matmul_kernel(a_ref, b_ref, o_ref, *, block_k: int, k_chunk: int,
-                   product_fn):
+def _matmul_kernel(a_ref, b_ref, o_ref, *, k_chunk: int, product_fn):
     k_idx = pl.program_id(2)
 
     @pl.when(k_idx == 0)
@@ -45,14 +46,12 @@ def _matmul_kernel(a_ref, b_ref, o_ref, *, block_k: int, k_chunk: int,
 
     a = a_ref[...].astype(jnp.int32)  # (bm, bk)
     b = b_ref[...].astype(jnp.int32)  # (bk, bn)
-
-    def body(j, acc):
-        a_s = jax.lax.dynamic_slice_in_dim(a, j * k_chunk, k_chunk, axis=1)
-        b_s = jax.lax.dynamic_slice_in_dim(b, j * k_chunk, k_chunk, axis=0)
+    acc = jnp.zeros(o_ref.shape, jnp.int32)
+    for k0 in range(0, a.shape[1], k_chunk):
+        a_s = a[:, k0:k0 + k_chunk]  # (bm, kc)
+        b_s = b[k0:k0 + k_chunk, :]  # (kc, bn)
         prod = product_fn(a_s[:, :, None], b_s[None, :, :])  # (bm, kc, bn)
-        return acc + prod.sum(axis=1)
-
-    acc = jax.lax.fori_loop(0, block_k // k_chunk, body, jnp.zeros_like(o_ref))
+        acc = acc + prod.sum(axis=1)
     o_ref[...] += acc
 
 
@@ -77,7 +76,7 @@ def approx_matmul_pallas(a, b, *, product_fn=approx_product_i32,
     k_chunk = resolve_k_chunk(k_chunk, block_k)
     grid = (m // block_m, n // block_n, k // block_k)
     return pl.pallas_call(
-        functools.partial(_matmul_kernel, block_k=block_k, k_chunk=k_chunk,
+        functools.partial(_matmul_kernel, k_chunk=k_chunk,
                           product_fn=product_fn),
         grid=grid,
         in_specs=[

@@ -18,7 +18,8 @@ from repro.core import lut as lut_lib
 from repro.core import multiplier as mult
 from repro.kernels import blocking
 from repro.kernels.approx_matmul.kernel import approx_matmul_pallas
-from repro.kernels.closed_form import closed_form_f00, make_closed_form
+from repro.kernels.closed_form import (approx_product_i32, closed_form_f00,
+                                       make_closed_form)
 from repro.obs.trace import trace_span
 
 
@@ -34,24 +35,40 @@ def _f00() -> int:
     return lut_lib.f00("proposed")
 
 
+def _swap(product_fn):
+    return lambda x, y: product_fn(y, x)
+
+
+def _contract(a, b, product_fn, f00, block_m, block_n, block_k, k_chunk):
+    """The kernel on arbitrary (M,K)@(K,N): padded, cropped, f(0,0)-corrected
+    and lane-dense oriented (``blocking.lane_dense``)."""
+    def run(fn):
+        return lambda x, y: blocking.pad_crop_correct(
+            x, y, f00,
+            lambda ap, bp, bm, bn, bk: approx_matmul_pallas(
+                ap, bp, product_fn=fn, block_m=bm, block_n=bn,
+                block_k=bk, k_chunk=k_chunk,
+                interpret=blocking.resolve_interpret()),
+            block_m=block_m, block_n=block_n, block_k=block_k)
+
+    a = jnp.asarray(a, jnp.int32)
+    b = jnp.asarray(b, jnp.int32)
+    return blocking.lane_dense(a, b, run(product_fn), run(_swap(product_fn)))
+
+
 @functools.partial(jax.jit,
                    static_argnames=("block_m", "block_n", "block_k", "k_chunk"))
 def _approx_matmul_jit(a, b, block_m, block_n, block_k, k_chunk):
-    a = jnp.asarray(a, jnp.int32)
-    b = jnp.asarray(b, jnp.int32)
-    return blocking.pad_crop_correct(
-        a, b, _f00(),
-        lambda ap, bp, bm, bn, bk: approx_matmul_pallas(
-            ap, bp, block_m=bm, block_n=bn, block_k=bk, k_chunk=k_chunk,
-            interpret=blocking.resolve_interpret()),
-        block_m=block_m, block_n=block_n, block_k=block_k)
+    return _contract(a, b, approx_product_i32, _f00(), block_m, block_n,
+                     block_k, k_chunk)
 
 
 def approx_matmul(a, b, block_m: int = 128, block_n: int = 128,
                   block_k: int = 128, k_chunk: int = 8):
     """(M,K) @ (K,N) under the proposed approximate multiplier.
 
-    Pads every dim to its block multiple. Zero-padding the contraction dim
+    Pads every dim to its block multiple (a dim that fits in one block is
+    taken whole; see ``blocking``). Zero-padding the contraction dim
     injects f(0,0)=192 per padded k element (the compensation constant fires
     on zero operands — faithful to the netlist), which is subtracted back.
     ``k_chunk=1`` recovers the pre-vectorization scalar k-walk (kept as the
@@ -70,15 +87,8 @@ def _closed_form_runner(key: str, block_m: int, block_n: int, block_k: int,
 
     @jax.jit
     def run(a, b):
-        a = jnp.asarray(a, jnp.int32)
-        b = jnp.asarray(b, jnp.int32)
-        return blocking.pad_crop_correct(
-            a, b, f00,
-            lambda ap, bp, bm, bn, bk: approx_matmul_pallas(
-                ap, bp, product_fn=product_fn, block_m=bm, block_n=bn,
-                block_k=bk, k_chunk=k_chunk,
-                interpret=blocking.resolve_interpret()),
-            block_m=block_m, block_n=block_n, block_k=block_k)
+        return _contract(a, b, product_fn, f00, block_m, block_n, block_k,
+                         k_chunk)
 
     return run
 

@@ -1,13 +1,20 @@
 """Shared plumbing for the Pallas kernel wrappers.
 
-Two concerns, one home, so the kernel paths cannot silently diverge:
+Three concerns, one home, so the kernel paths cannot silently diverge:
 
 * pad-to-block / crop / f(0,0)-correct for the matmul kernels
-  (``approx_matmul/ops.py``, ``lut_matmul/ops.py``): clamp the requested
-  block sizes to TPU-tileable minima, zero-pad every dim up, crop the
-  result, and subtract the multiplier's f(0,0) per padded k element
-  (approximate wirings map (0,0) to a nonzero compensation value, so
-  k-padding injects spurious contributions);
+  (``approx_matmul/ops.py``, ``lut_matmul/ops.py``): take an N or K dim
+  that fits in one block whole (a block equal to the full dim is always
+  tileable, and a 1-wide output padded to 128 lanes would cost 128× its
+  HBM bytes), round a short M up to whole sublanes, zero-pad the rest up
+  to block multiples, crop the result, and subtract the multiplier's
+  f(0,0) per padded k element (approximate wirings map (0,0) to a
+  nonzero compensation value, so k-padding injects spurious
+  contributions);
+* orientation (:func:`lane_dense`): a contraction with a narrow N and a
+  long M (the edge path's per-pixel tap dot, ``(B·H·W, taps) @ (taps,
+  1)``) runs as ``(Bᵀ Aᵀ)ᵀ`` under the swapped product, so the long dim
+  lies along the 128 lanes instead of a 1-wide output padded to 128;
 * interpret-mode selection (:func:`resolve_interpret`): one policy —
   explicit param beats the ``REPRO_PALLAS_INTERPRET`` env override beats
   the backend default — consumed by every ops wrapper instead of
@@ -22,9 +29,8 @@ import jax
 import jax.numpy as jnp
 
 # TPU int32 tile: the second-to-last dim aligns to 8 sublanes, the last to
-# 128 lanes — block clamps for small shapes round up to these.
+# 128 lanes — a short M rounds up to whole sublanes.
 SUBLANE, LANE = 8, 128
-_SUBLANE, _LANE = SUBLANE, LANE  # historical (pre-public) names
 
 #: env var forcing Pallas interpret mode on ("1"/"true"/...) or off.
 INTERPRET_ENV = "REPRO_PALLAS_INTERPRET"
@@ -39,23 +45,28 @@ def resolve_interpret(interpret: Optional[bool] = None) -> bool:
     Precedence: an explicit ``interpret`` argument wins; otherwise the
     ``REPRO_PALLAS_INTERPRET`` env var (``1/true/yes/on`` vs
     ``0/false/no/off``); otherwise interpret everywhere except on real TPU.
-    The ops wrappers call this at trace time, so inside a jitted wrapper
-    the decision is baked into the first trace for a given shape —
-    set the env var before the first kernel call, not between calls.
+    Interpret mode exists only off the TPU: asking for it on a TPU raises
+    instead of silently running the kernels in the interpreter. The ops
+    wrappers call this at trace time, so inside a jitted wrapper the
+    decision is baked into the first trace for a given shape — set the env
+    var before the first kernel call, not between calls.
     """
-    if interpret is not None:
-        return bool(interpret)
-    env = os.environ.get(INTERPRET_ENV)
-    if env is not None:
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        env = os.environ.get(INTERPRET_ENV)
+        if env is None:
+            return not on_tpu
         v = env.strip().lower()
-        if v in _TRUTHY:
-            return True
-        if v in _FALSY:
-            return False
-        raise ValueError(
-            f"{INTERPRET_ENV}={env!r} is neither truthy {_TRUTHY} nor "
-            f"falsy {_FALSY}")
-    return jax.default_backend() != "tpu"
+        if v not in _TRUTHY + _FALSY:
+            raise ValueError(
+                f"{INTERPRET_ENV}={env!r} is neither truthy {_TRUTHY} nor "
+                f"falsy {_FALSY}")
+        interpret = v in _TRUTHY
+    if interpret and on_tpu:
+        raise RuntimeError(
+            "Pallas interpret mode was requested on a TPU; it exists only "
+            f"for CPU runs (unset {INTERPRET_ENV} or pass interpret=False)")
+    return bool(interpret)
 
 
 def ceil_to(x: int, mult: int) -> int:
@@ -97,9 +108,9 @@ def pad_crop_correct(a, b, f00, kernel_call: Callable, *, block_m: int,
     m, k = a.shape
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)
-    bm = min(block_m, ceil_to(m, _SUBLANE))
-    bn = min(block_n, ceil_to(n, _LANE))
-    bk = min(block_k, ceil_to(k, _SUBLANE))
+    bm = min(block_m, ceil_to(m, SUBLANE))
+    bn = n if n <= block_n else block_n
+    bk = k if k <= block_k else block_k
     pm, pn, pk = (-m) % bm, (-n) % bn, (-k) % bk
     ap = jnp.pad(a, ((0, pm), (0, pk)))
     bp = jnp.pad(b, ((0, pk), (0, pn)))
@@ -107,3 +118,15 @@ def pad_crop_correct(a, b, f00, kernel_call: Callable, *, block_m: int,
     if pk:
         out = out - f00 * pk
     return out
+
+
+def lane_dense(a, b, run: Callable, run_swapped: Callable):
+    """``run(a, b)``, or ``run_swapped(bᵀ, aᵀ)ᵀ`` when N < 128 <= M.
+
+    ``run_swapped`` must contract under the swapped product g(x, y) =
+    f(y, x) — the approximate products are not symmetric — so that
+    ``Σ_k g(bᵀ[n,k], aᵀ[k,m]) = Σ_k f(a[m,k], b[k,n])`` term for term.
+    """
+    if b.shape[1] < LANE <= a.shape[0]:
+        return run_swapped(b.T, a.T).T
+    return run(a, b)

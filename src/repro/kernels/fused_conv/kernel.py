@@ -31,13 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _fused_kernel(*refs, taps, width_out, product_fn, has_table):
-    if has_table:  # flat product LUT rides along as a VMEM-resident input
-        view_refs, t_ref, o_ref = refs[:-2], refs[-2], refs[-1]
-        table = t_ref[...]
-    else:
-        view_refs, o_ref = refs[:-1], refs[-1]
-        table = None
+def _fused_kernel(*refs, taps, width_out, product_fn):
+    view_refs, o_ref = refs[:-1], refs[-1]
     w = width_out
     acc = jnp.zeros(o_ref.shape[1:], jnp.int32)  # (bh, w)
     for di, vref in enumerate(view_refs):
@@ -46,43 +41,35 @@ def _fused_kernel(*refs, taps, width_out, product_fn, has_table):
         maps = {}
         for c in row:
             if c not in maps:  # one product map per distinct coefficient
-                maps[c] = product_fn(tile, c, table)
+                maps[c] = product_fn(tile, c)
         for dj, c in enumerate(row):
             acc = acc + jax.lax.slice_in_dim(maps[c], dj, dj + w, axis=1)
     o_ref[...] = acc[None]
 
 
 def fused_conv_pallas(views, taps, product_fn, *, width_out: int,
-                      block_h: int, table=None, interpret: bool = False):
+                      block_h: int, interpret: bool = False):
     """Row-shifted views of the zero-padded batch → (B, Hb, W) conv response.
 
     views: tuple of ``kh`` arrays (B, Hb, Wp), view ``di`` holding rows
     ``di .. di+Hb`` of the padded batch (``Wp >= width_out + kw - 1``).
     taps: (kh, kw) nested tuples of static Python int coefficients.
-    product_fn: ``fn(tile, c, table)`` — elementwise approximate product of
-    an int32 tile with the static coefficient ``c``; ``table`` is the flat
-    (2^{2N},) product LUT when given (Pallas forbids captured array
-    constants, so table-driven strategies receive it as a kernel input) and
-    None otherwise. Hb must be a multiple of ``block_h`` (the ops wrapper
-    pads).
+    product_fn: ``fn(tile, c)`` — elementwise approximate product of an
+    int32 tile with the static coefficient ``c``. Hb must be a multiple of
+    ``block_h`` (the ops wrapper pads).
     """
     kh = len(taps)
     assert len(views) == kh, (len(views), kh)
     b, hb, wp = views[0].shape
     grid = (b, hb // block_h)
     view_spec = pl.BlockSpec((1, block_h, wp), lambda bb, i: (bb, i, 0))
-    in_specs = [view_spec] * kh
-    inputs = list(views)
-    if table is not None:
-        in_specs.append(pl.BlockSpec((table.shape[0],), lambda bb, i: (0,)))
-        inputs.append(table)
     return pl.pallas_call(
         functools.partial(_fused_kernel, taps=taps, width_out=width_out,
-                          product_fn=product_fn, has_table=table is not None),
+                          product_fn=product_fn),
         grid=grid,
-        in_specs=in_specs,
+        in_specs=[view_spec] * kh,
         out_specs=pl.BlockSpec((1, block_h, width_out),
                                lambda bb, i: (bb, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hb, width_out), jnp.int32),
         interpret=interpret,
-    )(*inputs)
+    )(*views)

@@ -7,10 +7,12 @@ tensor. Two product strategies, selected by ``kernel_kind``:
 * ``"closed_form"`` — the wiring's generated closed form
   (``kernels.closed_form.make_closed_form``): pure VPU integer algebra,
   partially constant-folded per static tap coefficient;
-* ``"lut"`` — the wiring's flat (2^{2N},) product LUT rides along as a
-  VMEM-resident kernel input; each distinct tap coefficient costs one
-  batched gather at a static column offset (the fallback for product
-  models with no CSP structure, e.g. ``"exact"``).
+* ``"lut"`` — the wiring's product table, read at trace time: each
+  distinct tap coefficient c selects one static table column, applied as
+  the exact product x·c plus a compare-select per entry where the column
+  deviates from it (Mosaic gathers only within one vreg, so the table
+  never becomes a kernel input). The fallback for product models with
+  no CSP structure, e.g. ``"exact"``, where no entry deviates.
 
 The default ``"auto"`` picks the closed form whenever the wiring has one
 and falls back to the LUT otherwise — same policy as ``PallasSubstrate``.
@@ -37,13 +39,21 @@ from repro.obs.trace import trace_span
 KERNEL_KINDS = ("auto", "closed_form", "lut")
 
 
-def _lut_tap_product(n_bits: int):
-    """Product fn gathering the flat table at a static column offset."""
+def lut_tap_product(key: str):
+    """Product fn ``fn(tile, c)`` reading the wiring's table column of c."""
+    table = lut_lib.build_lut(key).astype(np.int64)  # (2^N, 2^N)
+    n_bits = table.shape[0].bit_length() - 1
     off, mask = 1 << (n_bits - 1), (1 << n_bits) - 1
+    vals = np.arange(table.shape[0]) - off  # signed operand of each row
 
-    def fn(tile, c, table):
-        idx = (((tile + off) & mask) << n_bits) | ((int(c) + off) & mask)
-        return jnp.take(table, idx, axis=0)
+    def fn(tile, c):
+        cw = ((int(c) + off) & mask) - off  # c wrapped into the operand range
+        dev = table[:, cw + off] - vals * cw
+        x = mult.wrap_operand(tile, n_bits)
+        out = x * cw
+        for i in np.flatnonzero(dev):
+            out = out + jnp.where(x == int(vals[i]), int(dev[i]), 0)
+        return out
 
     return fn
 
@@ -51,7 +61,6 @@ def _lut_tap_product(n_bits: int):
 @functools.lru_cache(maxsize=None)
 def _fused_runner(key: str, kernel_kind: str, taps: tuple, block_h: int,
                   interpret: bool):
-    table = None
     if kernel_kind == "auto":
         try:
             make_closed_form(key)
@@ -59,12 +68,9 @@ def _fused_runner(key: str, kernel_kind: str, taps: tuple, block_h: int,
         except ValueError:  # no CSP wiring (e.g. "exact") — serve via LUT
             kernel_kind = "lut"
     if kernel_kind == "closed_form":
-        cf = make_closed_form(key)
-        product_fn = lambda tile, c, _table: cf(tile, c)  # noqa: E731
+        product_fn = make_closed_form(key)
     elif kernel_kind == "lut":
-        flat = lut_lib.flat_lut(key)
-        table = jnp.asarray(flat, jnp.int32)
-        product_fn = _lut_tap_product(flat.shape[0].bit_length() // 2)
+        product_fn = lut_tap_product(key)
     else:
         raise ValueError(
             f"unknown fused-conv kernel kind {kernel_kind!r} "
@@ -84,7 +90,7 @@ def _fused_runner(key: str, kernel_kind: str, taps: tuple, block_h: int,
             jax.lax.slice_in_dim(padded, di, di + hb, axis=1)
             for di in range(kh))
         out = fused_conv_pallas(views, taps, product_fn, width_out=w,
-                                block_h=bh, table=table, interpret=interpret)
+                                block_h=bh, interpret=interpret)
         return out[:, :h, :]
 
     return run

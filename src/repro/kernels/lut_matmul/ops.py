@@ -36,11 +36,17 @@ def _lut_matmul_jit(a, b, table, block_m, block_n, block_k, k_chunk):
     b = jnp.asarray(b, jnp.int32)
     table = jnp.asarray(table, jnp.int32)
     n_bits = table_width(table.shape[0])
-    off = 1 << (n_bits - 1)
+    size, off = 1 << n_bits, 1 << (n_bits - 1)
     f00 = table[(off << n_bits) | off]  # this wiring's product at (0,0)
-    return blocking.pad_crop_correct(
-        a, b, f00,
-        lambda ap, bp, bm, bn, bk: lut_matmul_pallas(
-            ap, bp, table, block_m=bm, block_n=bn, block_k=bk,
-            k_chunk=k_chunk, interpret=blocking.resolve_interpret()),
-        block_m=block_m, block_n=block_n, block_k=block_k)
+    # the swapped product g(x, y) = f(y, x) reads the transposed table
+    table_t = table.reshape(size, size).T.reshape(-1)
+
+    def run(t):
+        return lambda x, y: blocking.pad_crop_correct(
+            x, y, f00,
+            lambda ap, bp, bm, bn, bk: lut_matmul_pallas(
+                ap, bp, t, block_m=bm, block_n=bn, block_k=bk,
+                k_chunk=k_chunk, interpret=blocking.resolve_interpret()),
+            block_m=block_m, block_n=block_n, block_k=block_k)
+
+    return blocking.lane_dense(a, b, run(table), run(table_t))

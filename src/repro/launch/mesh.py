@@ -20,17 +20,33 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
+def make_mesh(shape, axes, devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto`` (sharding-propagated).
+
+    Since JAX 0.9 ``jax.make_mesh`` defaults to ``Explicit`` axes, whose
+    sharding becomes part of every array type: a shard_map output would
+    then carry its mesh sharding into the plain reshapes of the substrate
+    and model code, which raises ``ShardingTypeError``. All meshes of this
+    repo are built here.
+    """
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(n_devices: Optional[int] = None) -> Mesh:
-    """Small mesh over however many devices exist (tests)."""
+    """Small ("data", "model") mesh over the first ``n_devices`` devices
+    (default: all of them); "model" is 2 wide when the count is even."""
     n = n_devices or len(jax.devices())
     model = 2 if n % 2 == 0 else 1
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"),
+                     devices=jax.devices()[:n])
 
 
 def contraction_partitioning(mesh: Mesh, *, m_axis: str = "data",

@@ -18,6 +18,7 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import add_reduced_overrides, overrides_from
 from repro.models import registry as reg
 from repro.obs import Tracer, tracing_scope, write_chrome_trace, write_metrics
@@ -48,6 +49,7 @@ def main():
                          "serving spans")
     add_reduced_overrides(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = reg.get_config(args.arch, **overrides_from(args))
     bundle = reg._BUILDERS[cfg.family](cfg)

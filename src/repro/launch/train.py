@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.data import SyntheticLMStream
 from repro.launch import mesh as mesh_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry as reg
 from repro.nn import plan as plan_mod
 from repro.optim import adafactor, adamw, warmup_cosine
@@ -93,6 +94,7 @@ def main():
                          "(checkpoint.save_plan_bundle)")
     add_reduced_overrides(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = reg.get_config(args.arch, **overrides_from(args))
     bundle = reg._BUILDERS[cfg.family](cfg)

@@ -550,7 +550,6 @@ def _moe_block_ep(cfg: ModelConfig, p: Params, x: Array, mesh, dp) -> Array:
     cleanly in the backward pass.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     b, s, d = x.shape
     t = b * s
@@ -573,12 +572,12 @@ def _moe_block_ep(cfg: ModelConfig, p: Params, x: Array, mesh, dp) -> Array:
         return jax.lax.all_gather(y_mine, "model", axis=0, tiled=True)
 
     dp_spec = dp if len(dp) > 1 else dp[0]
-    y = shard_map(
+    y = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp_spec, None), P(), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
         out_specs=P(dp_spec, None),
-        check_rep=False,
+        check_vma=False,
     )(xn, p["router"], p["wi"], p["wg"], p["wo"])
 
     if cfg.shared_expert:

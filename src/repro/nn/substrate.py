@@ -43,8 +43,8 @@ Registered backends (``list_substrates()``):
 * ``approx_stat``     — exact int32 matmul + separable statistical error
                         model (MXU-friendly deployment stand-in). Widths ≤ 8
                         (the model is fit on the exhaustive error LUT).
-* ``approx_pallas``   — the tiled Pallas TPU kernels, interpret-mode
-                        fallback off-TPU; bit-identical to
+* ``approx_pallas``   — the tiled Pallas TPU kernels (interpret mode on
+                        the CPU only); bit-identical to
                         ``approx_bitexact``. Any wiring at widths 3..8:
                         CSP wirings run a *generated* closed-form VPU
                         kernel (``kernels.closed_form.make_closed_form``
@@ -575,7 +575,6 @@ def _sharded_dot(local_dot, a: Array, b: Array, part: Partitioning,
     is corrected once with it after the reduce; None means no such
     correction exists, so non-divisible K must raise before calling here.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m, k = a.shape
@@ -599,11 +598,11 @@ def _sharded_dot(local_dot, a: Array, b: Array, part: Partitioning,
                 out = jax.lax.psum(out, part.k_axis)
         return out
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=part.mesh,
         in_specs=(P(part.m_axis, part.k_axis), P(part.k_axis, None)),
         out_specs=P(part.m_axis, part.k_axis if scatter else None),
-        check_rep=False,
+        check_vma=False,
     )(a, b)
     if pk and k_pad_unit:
         out = out - k_pad_unit * pk
@@ -981,9 +980,10 @@ class PallasSubstrate(_SubstrateBase):
       through the vectorized-k-slab ``kernels/approx_matmul`` (cost hint
       ``vpu``). The default for every CSP wiring at every width 3..8 —
       non-proposed wirings no longer pay a per-product gather.
-    * ``"lut"`` — the LUT-input kernel (``kernels/lut_matmul``): one
-      gather per product into the wiring's flat (2^N · 2^N,) product
-      table, VMEM-resident for N ≤ 8 (cost hint ``gather``). The
+    * ``"lut"`` — the LUT-input kernel (``kernels/lut_matmul``): each
+      product is the wiring's table entry, selected by int8 one-hot
+      matmuls on the MXU against the VMEM-resident table (cost hint
+      ``mxu``). The
       automatic fallback for product models with no CSP closed form
       (``"exact"``); forceable with ``kernel="lut"`` for A/B benchmarks.
 
@@ -1023,7 +1023,7 @@ class PallasSubstrate(_SubstrateBase):
         self.meta = SubstrateMeta(
             "approx_pallas", base, bit_exact=True, scalar_faithful=True,
             preferred_backend="tpu",
-            cost_hint="vpu" if self._product_fn else "gather", width=n)
+            cost_hint="vpu" if self._product_fn else "mxu", width=n)
 
     def _table(self) -> Array:
         return jnp.asarray(lut_lib.flat_lut(self._key))

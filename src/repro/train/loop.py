@@ -247,7 +247,6 @@ def dp_train_step_compressed(loss_fn, optimizer, mesh, axis_name: str = "data",
     bytes on the wire — the paper's quantization theme applied to the
     collective layer).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def sharded_step(params, opt_state, batch, lr):
@@ -262,9 +261,9 @@ def dp_train_step_compressed(loss_fn, optimizer, mesh, axis_name: str = "data",
         return loss, new_params, new_state
 
     pspec_batch = P(axis_name)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         sharded_step, mesh=mesh,
         in_specs=(P(), P(), {"tokens": pspec_batch, "labels": pspec_batch}, P()),
         out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     ))

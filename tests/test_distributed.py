@@ -35,6 +35,7 @@ def test_compressed_dp_step_matches_uncompressed():
     """int8-compressed gradient all-reduce ≈ exact pmean on 8 devices."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.train.loop import dp_train_step_compressed
         from repro.optim import adamw
@@ -44,7 +45,7 @@ def test_compressed_dp_step_matches_uncompressed():
             tgt = batch["labels"].astype(jnp.float32)
             return jnp.mean((pred - tgt[..., None]) ** 2)
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         params = {"w": jnp.ones((16, 1), jnp.float32) * 0.1}
         opt = adamw(weight_decay=0.0)
         state = opt.init(params)
@@ -76,7 +77,7 @@ def test_dryrun_cell_on_debug_mesh():
                              vocab=512, n_heads=4, n_kv_heads=2,
                              attn_chunk=64, loss_chunk=64, remat=False)
         bundle = reg._BUILDERS[cfg.family](cfg)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = mesh_lib.make_mesh((4, 2), ("data", "model"))
         opt = adamw()
         with mesh:
             params_sds = reg.param_specs(bundle)
@@ -111,15 +112,16 @@ def test_elastic_checkpoint_restore_across_meshes(tmp_path):
     """Save params sharded on a (4,2) mesh; restore onto (2,4) and 1-device."""
     out = run_py(f"""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import save_checkpoint, load_checkpoint
 
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = make_mesh((4, 2), ("data", "model"))
         w = jnp.arange(64.0).reshape(8, 8)
         wa = jax.device_put(w, NamedSharding(mesh_a, P("data", "model")))
         save_checkpoint({str(tmp_path)!r}, 1, {{"w": wa}})
 
-        mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_b = make_mesh((2, 4), ("data", "model"))
         tgt = {{"w": jax.ShapeDtypeStruct((8, 8), jnp.float32)}}
         sh = {{"w": NamedSharding(mesh_b, P("model", "data"))}}
         tree, step, _ = load_checkpoint({str(tmp_path)!r}, {{"w": w}}, shardings=sh)
@@ -179,7 +181,7 @@ def test_sharded_edge_detect_matches_unsharded():
         from repro.launch import mesh as mesh_lib
         from repro.nn import conv
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = mesh_lib.make_mesh((4, 2), ("data", "model"))
         part = mesh_lib.contraction_partitioning(mesh)
         imgs = image_batch(4, 24, 24)
         for spec in ("approx_bitexact", "approx_lut:design_strollo2020"):
@@ -206,7 +208,7 @@ def test_dryrun_partitioned_approx_substrate_lowers():
                              attn_chunk=64, loss_chunk=64, remat=False,
                              dot_mode="approx_stat")
         bundle = reg._BUILDERS[cfg.family](cfg)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = mesh_lib.make_mesh((4, 2), ("data", "model"))
         part = mesh_lib.contraction_partitioning(mesh)
         assert (part.m_axis, part.k_axis) == ("data", "model")
         with mesh, psub.partitioning_scope(part):

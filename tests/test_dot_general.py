@@ -19,6 +19,7 @@ import pytest
 
 from test_distributed import run_py
 
+from repro.launch.mesh import make_mesh
 from repro.nn import conv
 from repro.nn import substrate as sub
 from repro.nn.substrate import ContractionSpec, Partitioning, QuantPolicy
@@ -235,7 +236,7 @@ def test_zero_image_gives_zero_edge_map(spec):
 
 
 def _mesh1():
-    return jax.make_mesh((1,), ("data",))
+    return make_mesh((1,), ("data",))
 
 
 def test_partitioning_validation():
@@ -244,7 +245,7 @@ def test_partitioning_validation():
         Partitioning(mesh, m_axis=None, k_axis=None)
     with pytest.raises(ValueError, match="not a mesh axis"):
         Partitioning(mesh, m_axis="model")
-    mesh2 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh2 = make_mesh((1, 1), ("data", "model"))
     with pytest.raises(ValueError, match="must differ"):
         Partitioning(mesh2, m_axis="data", k_axis="data")
 
@@ -298,11 +299,12 @@ def test_sharded_bit_identity_8_devices():
     divide the k axis."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.nn import substrate as sub
         from repro.nn.substrate import ContractionSpec, Partitioning
 
         rng = np.random.default_rng(3)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         parts = [
             Partitioning(mesh, m_axis="data"),                  # M only
             Partitioning(mesh, m_axis=None, k_axis="model"),    # K only
@@ -341,12 +343,13 @@ def test_sharded_quantized_float_path_8_devices():
     partial sums reduce exactly; the scales are computed unsharded)."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.nn import substrate as sub
         from repro.nn.substrate import ContractionSpec, Partitioning, \\
             QuantPolicy
 
         rng = np.random.default_rng(5)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         part = Partitioning(mesh, m_axis="data", k_axis="model")
         x = jnp.asarray(rng.normal(size=(6, 40)).astype(np.float32))
         w = jnp.asarray(rng.normal(size=(40, 8)).astype(np.float32))
@@ -372,10 +375,11 @@ def test_sharded_stat_requires_divisible_k():
     product, so the k-pad f(0,0) fix-up can't apply — loud error."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.nn import substrate as sub
         from repro.nn.substrate import ContractionSpec, Partitioning
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         part = Partitioning(mesh, m_axis="data", k_axis="model")
         s = sub.get_substrate("approx_stat")
         a = jnp.zeros((4, 19), jnp.int8)   # K=19 not divisible by 4

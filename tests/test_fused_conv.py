@@ -131,6 +131,22 @@ def test_kslab_ragged_k_padding():
         np.testing.assert_array_equal(got, want, err_msg=f"kc={kc}")
 
 
+@pytest.mark.parametrize("mkn", [(256, 9, 1), (300, 200, 3), (129, 1, 127)])
+def test_lane_dense_orientation_matches_bitexact(mkn):
+    """A narrow N with a long M runs as (Bᵀ Aᵀ)ᵀ under the swapped product;
+    an asymmetric wiring and a padded K (200) keep both the operand order
+    and the f(0,0) correction honest there."""
+    m, k, n = mkn
+    a, b = _img(m, k), _img(k, n)
+    key = "design_du2022@6"
+    want = np.asarray(
+        sub.get_substrate(f"approx_bitexact:{key}").dot_int(a, b))
+    got = np.asarray(closed_form_matmul(a, b, key))
+    np.testing.assert_array_equal(got, want, err_msg="closed form")
+    got = np.asarray(lut_matmul(a, b, lut_lib.flat_lut(key)))
+    np.testing.assert_array_equal(got, want, err_msg="lut")
+
+
 # ---------------------------------------------------------------------------
 # fused conv vs the im2col reference path
 # ---------------------------------------------------------------------------
@@ -271,4 +287,19 @@ def test_resolve_interpret_precedence(monkeypatch):
     assert blocking.resolve_interpret() is True
     monkeypatch.setenv(blocking.INTERPRET_ENV, "bogus")
     with pytest.raises(ValueError, match=blocking.INTERPRET_ENV):
+        blocking.resolve_interpret()
+
+
+def test_resolve_interpret_refuses_tpu(monkeypatch):
+    """On a TPU the kernels compile with Mosaic; a request for interpret
+    mode there (env or argument) raises instead of running the
+    interpreter."""
+    monkeypatch.setattr(blocking.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv(blocking.INTERPRET_ENV, raising=False)
+    assert blocking.resolve_interpret() is False
+    assert blocking.resolve_interpret(False) is False
+    with pytest.raises(RuntimeError, match="interpret mode"):
+        blocking.resolve_interpret(True)
+    monkeypatch.setenv(blocking.INTERPRET_ENV, "1")
+    with pytest.raises(RuntimeError, match="interpret mode"):
         blocking.resolve_interpret()

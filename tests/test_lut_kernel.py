@@ -200,9 +200,9 @@ def test_pallas_substrate_fast_path_vs_lut_path_metadata():
     assert sub.get_substrate(
         "approx_pallas:design_du2022").meta.cost_hint == "vpu"
     # the LUT kernel remains as the non-CSP fallback and an explicit opt-in
-    assert sub.get_substrate("approx_pallas:exact").meta.cost_hint == "gather"
+    assert sub.get_substrate("approx_pallas:exact").meta.cost_hint == "mxu"
     forced = sub.PallasSubstrate("design_du2022", kernel="lut")
-    assert forced.meta.cost_hint == "gather"
+    assert forced.meta.cost_hint == "mxu"
     with pytest.raises(ValueError, match="unknown multiplier wiring"):
         sub.PallasSubstrate("exact", kernel="closed_form")
 
