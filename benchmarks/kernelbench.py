@@ -152,19 +152,7 @@ def _fused_conv_rows(rng, records, dry_run) -> list:
 
 
 def run(substrates=None, sharded=False, dry_run=False,
-        json_path=DEFAULT_JSON, trace_path=None) -> list:
-    from repro.obs import Tracer, tracing_scope, write_chrome_trace
-
-    tracer = Tracer() if trace_path else None
-    with tracing_scope(tracer):
-        rows = _run_benches(substrates, sharded, dry_run, json_path)
-    if trace_path:
-        p = write_chrome_trace(tracer, trace_path)
-        print(f"wrote {len(tracer.events())} trace events to {p}")
-    return rows
-
-
-def _run_benches(substrates, sharded, dry_run, json_path) -> list:
+        json_path=DEFAULT_JSON) -> list:
     rows = []
     records: list[dict] = []
     rng = np.random.default_rng(0)
@@ -257,14 +245,10 @@ def main() -> None:
                     help="CSV of substrate specs (default: all registered)")
     ap.add_argument("--json", default=str(DEFAULT_JSON), dest="json_path",
                     help="output path for BENCH_kernels.json ('' disables)")
-    ap.add_argument("--trace", default=None, dest="trace_path",
-                    help="write a Chrome/Perfetto trace of the kernel "
-                         "dispatch spans")
     args = ap.parse_args()
     substrates = args.substrates.split(",") if args.substrates else None
     rows = run(substrates=substrates, sharded=args.sharded,
-               dry_run=args.dry_run, json_path=args.json_path or None,
-               trace_path=args.trace_path)
+               dry_run=args.dry_run, json_path=args.json_path or None)
     print("\nname,us_per_call,derived")
     for name, us, derived in rows:
         print(f"{name},{us:.1f},{derived}")

@@ -16,8 +16,8 @@ energy rollup), and the ``autotune`` bench writes ``BENCH_autotune.json``
 instead of searching), and the ``qat`` bench writes ``BENCH_qat.json``
 (pre/post-QAT quality across wirings × widths + recovered operating
 points) at the repo root, so one ``python -m benchmarks.run`` produces the
-full perf trajectory. Trace files are opt-in via each bench's
-standalone ``--trace`` flag.
+full perf trajectory. A span trace of the serving run is opt-in via
+``benchmarks/edge_serving.py --trace``.
 """
 from __future__ import annotations
 

@@ -58,7 +58,8 @@ def _matmul_kernel(a_ref, b_ref, o_ref, *, k_chunk: int, product_fn):
 def approx_matmul_pallas(a, b, *, product_fn=approx_product_i32,
                          block_m: int = 128, block_n: int = 128,
                          block_k: int = 128, k_chunk: int = 8,
-                         interpret: bool = False):
+                         interpret: bool = False,
+                         name: str = "approx_matmul"):
     """(M,K) @ (K,N) int-domain contraction under ``product_fn``.
 
     a: (M, K) int32 operands in the model's domain; b: (K, N) int32.
@@ -86,4 +87,5 @@ def approx_matmul_pallas(a, b, *, product_fn=approx_product_i32,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
+        name=name,
     )(a, b)

@@ -20,7 +20,6 @@ from repro.kernels import blocking
 from repro.kernels.approx_matmul.kernel import approx_matmul_pallas
 from repro.kernels.closed_form import (approx_product_i32, closed_form_f00,
                                        make_closed_form)
-from repro.obs.trace import trace_span
 
 
 @functools.lru_cache(maxsize=None)
@@ -39,16 +38,18 @@ def _swap(product_fn):
     return lambda x, y: product_fn(y, x)
 
 
-def _contract(a, b, product_fn, f00, block_m, block_n, block_k, k_chunk):
+def _contract(a, b, product_fn, f00, block_m, block_n, block_k, k_chunk,
+              name):
     """The kernel on arbitrary (M,K)@(K,N): padded, cropped, f(0,0)-corrected
-    and lane-dense oriented (``blocking.lane_dense``)."""
+    and lane-dense oriented (``blocking.lane_dense``). ``name`` names the
+    Pallas call, which is how the device trace shows the kernel."""
     def run(fn):
         return lambda x, y: blocking.pad_crop_correct(
             x, y, f00,
             lambda ap, bp, bm, bn, bk: approx_matmul_pallas(
                 ap, bp, product_fn=fn, block_m=bm, block_n=bn,
                 block_k=bk, k_chunk=k_chunk,
-                interpret=blocking.resolve_interpret()),
+                interpret=blocking.resolve_interpret(), name=name),
             block_m=block_m, block_n=block_n, block_k=block_k)
 
     a = jnp.asarray(a, jnp.int32)
@@ -60,7 +61,7 @@ def _contract(a, b, product_fn, f00, block_m, block_n, block_k, k_chunk):
                    static_argnames=("block_m", "block_n", "block_k", "k_chunk"))
 def _approx_matmul_jit(a, b, block_m, block_n, block_k, k_chunk):
     return _contract(a, b, approx_product_i32, _f00(), block_m, block_n,
-                     block_k, k_chunk)
+                     block_k, k_chunk, "approx_matmul")
 
 
 def approx_matmul(a, b, block_m: int = 128, block_n: int = 128,
@@ -74,9 +75,7 @@ def approx_matmul(a, b, block_m: int = 128, block_n: int = 128,
     ``k_chunk=1`` recovers the pre-vectorization scalar k-walk (kept as the
     benchmark baseline).
     """
-    (m, k), (_, n) = jnp.shape(a), jnp.shape(b)
-    with trace_span("kernel.approx_matmul", "kernel", m=m, k=k, n=n):
-        return _approx_matmul_jit(a, b, block_m, block_n, block_k, k_chunk)
+    return _approx_matmul_jit(a, b, block_m, block_n, block_k, k_chunk)
 
 
 @functools.lru_cache(maxsize=None)
@@ -86,11 +85,11 @@ def _closed_form_runner(key: str, block_m: int, block_n: int, block_k: int,
     f00 = closed_form_f00(key)
 
     @jax.jit
-    def run(a, b):
+    def closed_form_matmul_run(a, b):
         return _contract(a, b, product_fn, f00, block_m, block_n, block_k,
-                         k_chunk)
+                         k_chunk, "closed_form_matmul")
 
-    return run
+    return closed_form_matmul_run
 
 
 def closed_form_matmul(a, b, mult_key: str = "proposed", *,
@@ -103,8 +102,4 @@ def closed_form_matmul(a, b, mult_key: str = "proposed", *,
     (wiring, block sizes, k_chunk).
     """
     key = mult.canonical_key(mult_key)
-    run = _closed_form_runner(key, block_m, block_n, block_k, k_chunk)
-    (m, k), (_, n) = jnp.shape(a), jnp.shape(b)
-    with trace_span("kernel.closed_form_matmul", "kernel", mult=key,
-                    m=m, k=k, n=n):
-        return run(a, b)
+    return _closed_form_runner(key, block_m, block_n, block_k, k_chunk)(a, b)

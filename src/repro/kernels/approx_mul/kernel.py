@@ -33,4 +33,5 @@ def approx_mul_pallas(a, b, *, block_m: int = 256, block_n: int = 128,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         interpret=interpret,
+        name="approx_mul",
     )(a, b)

@@ -72,4 +72,5 @@ def fused_conv_pallas(views, taps, product_fn, *, width_out: int,
                                lambda bb, i: (bb, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hb, width_out), jnp.int32),
         interpret=interpret,
+        name="fused_conv",
     )(*views)

@@ -34,7 +34,6 @@ from repro.core import multiplier as mult
 from repro.kernels import blocking
 from repro.kernels.closed_form import make_closed_form
 from repro.kernels.fused_conv.kernel import fused_conv_pallas
-from repro.obs.trace import trace_span
 
 KERNEL_KINDS = ("auto", "closed_form", "lut")
 
@@ -111,7 +110,4 @@ def fused_conv2d(imgs, kernel, mult_key: str = "proposed", *,
     key = mult.canonical_key(mult_key)
     run = _fused_runner(key, kernel_kind, taps, block_h,
                         blocking.resolve_interpret(interpret))
-    shape = jnp.shape(imgs)
-    with trace_span("kernel.fused_conv2d", "kernel", mult=key,
-                    shape="x".join(map(str, shape))):
-        return run(imgs)
+    return run(imgs)

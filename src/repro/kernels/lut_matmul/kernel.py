@@ -144,4 +144,5 @@ def lut_matmul_pallas(a, b, table, *, block_m: int = 128, block_n: int = 128,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
+        name="lut_matmul",
     )(a, b, t_hi, t_lo)

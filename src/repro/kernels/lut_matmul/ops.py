@@ -8,7 +8,6 @@ import jax.numpy as jnp
 
 from repro.kernels import blocking
 from repro.kernels.lut_matmul.kernel import lut_matmul_pallas, table_width
-from repro.obs.trace import trace_span
 
 
 def lut_matmul(a, b, table, block_m: int = 128, block_n: int = 128,
@@ -23,10 +22,8 @@ def lut_matmul(a, b, table, block_m: int = 128, block_n: int = 128,
     and subtracted back. ``k_chunk=1`` recovers the pre-vectorization
     per-k gather walk (kept as the benchmark baseline).
     """
-    (m, k), (_, n) = jnp.shape(a), jnp.shape(b)
-    with trace_span("kernel.lut_matmul", "kernel", m=m, k=k, n=n):
-        return _lut_matmul_jit(a, b, table, block_m, block_n, block_k,
-                               k_chunk)
+    return _lut_matmul_jit(a, b, table, block_m, block_n, block_k,
+                           k_chunk)
 
 
 @functools.partial(jax.jit,

@@ -13,6 +13,11 @@ event list. Export surfaces:
 * :class:`JsonlSink` — streams every completed span to a file as one JSON
   object per line (``tracer.add_sink(sink)``).
 
+Every span is also a ``jax.profiler.TraceAnnotation`` of the same name and
+attributes, so any ``jax.profiler`` capture (xprof, Perfetto, the
+benchmark's ``--trace 1``) shows the spans on the host lane, on the same
+clock as the device operations they dispatch or wait for.
+
 Ambient installation mirrors the meter scope
 (:func:`repro.obs.meter.telemetry_scope`): :func:`tracing_scope` installs a
 tracer *process-wide* — deliberately not thread-local, because serving
@@ -97,18 +102,31 @@ class Tracer:
 
     # -- recording -----------------------------------------------------------
 
+    def now(self) -> float:
+        """A raw ``clock`` reading, on the scale :meth:`event` takes."""
+        return self._clock()
+
     @contextlib.contextmanager
     def span(self, name: str, cat: str = "", **attrs):
-        """Record a span around the block; nests on this thread's stack."""
+        """Record a span around the block; nests on this thread's stack.
+
+        The block is mirrored into the profiler as a
+        ``jax.profiler.TraceAnnotation`` (a no-op unless a capture runs).
+        """
+        from jax.profiler import TraceAnnotation
+
         stack = self._stack()
         depth = len(stack)
         parent = stack[-1] if stack else None
         stack.append(name)
+        annotation = TraceAnnotation(name, **attrs)
+        annotation.__enter__()
         ts = self._now_us()
         try:
             yield self
         finally:
             dur = self._now_us() - ts
+            annotation.__exit__(None, None, None)
             stack.pop()
             args: Dict[str, Any] = {"depth": depth}
             if parent is not None:
@@ -122,9 +140,11 @@ class Tracer:
               cat: str = "", **attrs) -> None:
         """Record a retroactive span from absolute ``clock`` readings.
 
-        ``start_s`` is a raw ``clock()`` value (e.g. a ticket's
-        ``enqueued_at``) — used for intervals measured outside a ``with``
-        block, like queue-wait time.
+        ``start_s`` is a raw ``clock()`` value (see :meth:`now`) — used for
+        intervals known only after they end, like queue-wait time. A
+        profiler annotation can only be opened and closed as its interval
+        happens, so a retroactive span is not mirrored into the profiler:
+        it appears in this tracer's own export alone.
         """
         self._emit({"name": name, "cat": cat or "span", "ph": "X",
                     "ts": (start_s - self._t0) * 1e6, "dur": dur_s * 1e6,

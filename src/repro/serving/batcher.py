@@ -349,18 +349,20 @@ class MicroBatcher:
 
     def _serve(self, key: Hashable, batch: List[Ticket], reason: str,
                worker: str = "drain") -> None:
-        t_busy = self._clock()
+        t_busy = self._clock()  # the batch was popped just now
         try:
             self.metrics.record_batch(len(batch), reason, self.max_batch_size)
             tracer = current_tracer()
             if tracer is not None:
-                # retroactive span: the head ticket's time in queue. Only
-                # meaningful when the batcher runs on the tracer's clock
-                # (both default to time.perf_counter).
-                head = min(t.enqueued_at for t in batch)
-                tracer.event("batch.queue_wait", head, self._clock() - head,
+                # retroactive span: the head ticket's time in queue, ending
+                # at the tracer's now; every wait is measured on the
+                # batcher's clock, so the two clocks need not agree
+                waits = [t_busy - t.enqueued_at for t in batch]
+                head = max(waits)
+                tracer.event("batch.queue_wait", tracer.now() - head, head,
                              "serving", bucket=str(key), size=len(batch),
-                             reason=reason, worker=worker)
+                             reason=reason, worker=worker,
+                             waits_ms=[w * 1e3 for w in waits])
             results, errs = self._run_batch(key, batch, reason, worker)
         except BaseException as e:  # noqa: BLE001 - telemetry failure: still
             # deliver something so no ticket blocks forever

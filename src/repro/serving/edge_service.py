@@ -47,7 +47,7 @@ import numpy as np
 
 from repro.nn import conv
 from repro.nn import substrate as sub
-from repro.obs.trace import trace_span
+from repro.obs.trace import current_tracer, trace_span
 from repro.serving.batcher import MicroBatcher, Ticket
 from repro.serving.metrics import ServingMetrics
 
@@ -203,11 +203,21 @@ class EdgeDetectService:
         return out, [im.shape for im in imgs]
 
     def _finalize(self, bucket: Tuple[int, int], raw) -> List[np.ndarray]:
-        """Delivery phase: block until the dispatched batch is ready, then
-        crop each map back to its request shape."""
+        """Delivery phase: block until the dispatched batch is ready and on
+        the host, then crop each map back to its request shape. Under a
+        tracer the copy is timed apart as ``edge.fetch``; untraced, one
+        ``np.asarray`` waits and copies."""
         out_dev, shapes = raw
         with trace_span("edge.wait", "serving", size=len(shapes)):
-            out = np.asarray(out_dev)      # implicit block_until_ready
+            if current_tracer() is None:
+                out = np.asarray(out_dev)      # implicit block_until_ready
+            else:
+                # traced only: the device->host copy runs on no device op,
+                # so time it apart from the wait for the computation
+                out_dev.block_until_ready()
+                with trace_span("edge.fetch", "serving",
+                                bytes=out_dev.nbytes, size=len(shapes)):
+                    out = np.asarray(out_dev)
         with trace_span("edge.crop", "serving", size=len(shapes)):
             return [out[i, :h, :w] for i, (h, w) in enumerate(shapes)]
 

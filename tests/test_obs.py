@@ -212,6 +212,55 @@ class TestTracer:
         assert current_tracer() is None
         assert [e["name"] for e in t.events()] == ["ambient"]
 
+    def test_span_is_mirrored_into_the_profiler(self, tmp_path):
+        """A span shows on the profiler's host plane under its own name and
+        attributes, as long as the tracer measured it."""
+        import glob
+        import time
+
+        from jax.profiler import ProfileData
+
+        t = Tracer()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with t.span("obs.mirrored", "test", size=3, bucket="48x80"):
+                time.sleep(0.02)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        found = [ev for plane in ProfileData.from_file(path).planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for ev in line.events
+                 if ev.name == "obs.mirrored"]
+        assert len(found) == 1
+        stats = {k: v for k, v in found[0].stats}
+        assert stats["size"] == 3 and stats["bucket"] == "48x80"
+        (span,) = t.events()
+        profiled_us = (found[0].end_ns - found[0].start_ns) / 1e3
+        assert abs(profiled_us - span["dur"]) < 1e3   # within 1 ms
+
+    def test_disabled_span_is_shared_and_imports_nothing(self, monkeypatch):
+        import builtins
+
+        from repro.obs import trace as trace_mod
+
+        imported = []
+        real_import = builtins.__import__
+
+        def spy(name, *args, **kwargs):
+            imported.append(name)
+            return real_import(name, *args, **kwargs)
+
+        assert current_tracer() is None
+        monkeypatch.setattr(builtins, "__import__", spy)
+        span = trace_span("off", "test", size=1)
+        with span:
+            pass
+        monkeypatch.undo()
+        assert span is trace_mod._NULL_SPAN
+        assert imported == []
+
 
 # ---------------------------------------------------------------------------
 # meters

@@ -112,6 +112,34 @@ def test_flush_on_timeout_partial_batch():
     assert all(t.latency_s == pytest.approx(1.1) for t in tickets)
 
 
+def test_queue_wait_carries_every_wait_on_the_tracers_clock():
+    """``batch.queue_wait`` holds each ticket's enqueue-to-pop wait, exact on
+    the batcher's FakeClock, and ends at the tracer's now although the
+    tracer runs on a clock 1000 s apart."""
+    from repro.obs.trace import Tracer, tracing_scope
+
+    calls = []
+    clock = FakeClock()
+    tracer_clock = FakeClock()
+    tracer_clock.t = 1000.0
+    tracer = Tracer(clock=tracer_clock)           # its zero is at 1000 s
+    b = _echo_batcher(calls, max_batch_size=8, max_wait_s=60.0, clock=clock)
+    b.submit(1)
+    clock.advance(0.25)
+    b.submit(2)
+    clock.advance(0.5)
+    b.submit(3)
+    clock.advance(0.125)
+    tracer_clock.t = 1007.0
+    with tracing_scope(tracer):
+        b.flush()
+    (ev,) = [e for e in tracer.events() if e["name"] == "batch.queue_wait"]
+    assert ev["args"]["waits_ms"] == [875.0, 625.0, 125.0]
+    assert ev["args"]["size"] == 3 and ev["args"]["reason"] == "drain"
+    assert ev["dur"] == pytest.approx(875e3)
+    assert ev["ts"] + ev["dur"] == pytest.approx(7e6)   # the tracer's now
+
+
 def test_bucket_isolation_and_sync_flush():
     """Buckets never mix inside a batch; flush() drains without a worker."""
     calls = []
@@ -324,6 +352,48 @@ def test_edge_service_noise_and_uint8_roundtrip():
         svc.close()
     ref = np.asarray(conv.edge_detect_batched(imgs, "approx_lut"))
     np.testing.assert_array_equal(np.stack(outs), ref)
+
+
+class _DeviceValue:
+    """Stands in for a dispatched batch; logs how delivery reads it."""
+
+    def __init__(self, a):
+        self.a, self.nbytes, self.calls = a, a.nbytes, []
+
+    def block_until_ready(self):
+        self.calls.append("block")
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        self.calls.append("copy")
+        return self.a
+
+
+def test_edge_service_delivery_splits_the_copy_only_when_traced():
+    """Untraced, delivery is one ``np.asarray`` (wait and copy together);
+    under a tracer it blocks first and times the copy as ``edge.fetch``
+    inside ``edge.wait``. Both crop the same maps."""
+    from repro.obs.trace import Tracer, tracing_scope
+
+    svc = EdgeDetectService("exact", start=False)
+    a = np.arange(32, dtype=np.uint8).reshape(2, 4, 4)
+    shapes = [(4, 4), (3, 2)]
+    plain = _DeviceValue(a)
+    outs = svc._finalize((16, 16), (plain, shapes))
+    assert plain.calls == ["copy"]
+    traced, tracer = _DeviceValue(a), Tracer()
+    with tracing_scope(tracer):
+        outs_traced = svc._finalize((16, 16), (traced, shapes))
+    assert traced.calls == ["block", "copy"]
+    for out, out_traced, (h, w), full in zip(outs, outs_traced, shapes, a):
+        np.testing.assert_array_equal(out, full[:h, :w])
+        np.testing.assert_array_equal(out_traced, out)
+    ev = {e["name"]: e for e in tracer.events()}
+    fetch, wait = ev["edge.fetch"], ev["edge.wait"]
+    assert fetch["args"]["parent"] == "edge.wait"
+    assert fetch["args"]["bytes"] == 32 and fetch["args"]["size"] == 2
+    assert wait["ts"] <= fetch["ts"]
+    assert fetch["ts"] + fetch["dur"] <= wait["ts"] + wait["dur"]
 
 
 def test_edge_service_rejects_bad_inputs():
