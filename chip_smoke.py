@@ -140,6 +140,8 @@ def phase_edge(imgs) -> None:
     from repro.serving import EdgeDetectService
 
     with EdgeDetectService("approx_pallas", max_batch_size=8) as svc:
+        check(svc.conv_path == "fused",
+              f"edge approx_pallas serves on {svc.conv_path}, not fused conv")
         served = serve(svc, imgs, "edge approx_pallas")
         for shape in {im.shape for im in imgs}:
             assert_mosaic(svc._jit_fn, padded_batch(svc, shape),

@@ -115,6 +115,17 @@ def _meter_fused(s, imgs: Array, kernel_arr: Array, site=None) -> None:
                     kernel_arr.reshape(1, kh * kw, 1), site=site)
 
 
+def takes_fused_path(substrate, kernel, partitioning=None) -> bool:
+    """Whether :func:`conv2d_batched` can run ``kernel`` on the substrate's
+    fused conv kernel: the substrate exposes ``fused_conv2d`` (the Pallas
+    backends), no partitioning is requested, and the taps are concrete (a
+    numpy array or Python ints; a traced kernel cannot specialize the
+    fused kernel). Judged on the caller's object: ``jnp.asarray`` of the
+    taps inside a jit is a tracer, the numpy array it wraps is not."""
+    return (partitioning is None and hasattr(substrate, "fused_conv2d")
+            and not isinstance(kernel, jax.core.Tracer))
+
+
 def conv2d_batched(imgs: Array, kernel: Array,
                    substrate: "str | object" = "approx_bitexact",
                    partitioning=None, fused: "bool | None" = None,
@@ -137,11 +148,9 @@ def conv2d_batched(imgs: Array, kernel: Array,
 
     ``fused`` selects the substrate's fused conv kernel (in-kernel im2col,
     no host-side patch tensor — ``kernels/fused_conv``): ``None`` (default)
-    auto-picks it whenever the substrate exposes ``fused_conv2d`` (the
-    Pallas backends), no partitioning was requested, and the kernel taps
-    are concrete (a traced kernel cannot specialize the fused kernel);
-    ``True`` forces it (raising where unavailable); ``False`` forces the
-    im2col reference path. Both paths are bit-identical — the fused kernel
+    auto-picks it where :func:`takes_fused_path` holds; ``True`` forces it
+    (raising where unavailable); ``False`` forces the im2col reference
+    path. Both paths are bit-identical — the fused kernel
     contracts exactly the same zero-padded tap products in the same int32
     ring.
     """
@@ -155,15 +164,10 @@ def conv2d_batched(imgs: Array, kernel: Array,
         imgs = imgs.transpose(0, 3, 1, 2).reshape(b * c, h, w)
     if imgs.ndim != 3:
         raise ValueError(f"imgs must be (B,H,W) or (B,H,W,C); got {imgs.shape}")
-    # concreteness is judged on the caller's object: a closed-over constant
-    # kernel stays fused-eligible inside an outer jit (jnp.asarray would
-    # re-wrap it as a tracer there), while a jit *argument* falls back
-    taps_concrete = not isinstance(kernel, jax.core.Tracer)
+    if fused is None:
+        fused = takes_fused_path(s, kernel, partitioning)
     kernel_arr = jnp.asarray(kernel, jnp.int32)
     kh, kw = kernel_arr.shape
-    if fused is None:
-        fused = (partitioning is None and hasattr(s, "fused_conv2d")
-                 and taps_concrete)
     if fused:
         if not hasattr(s, "fused_conv2d"):
             raise ValueError(
@@ -219,7 +223,8 @@ def edge_detect_batched(imgs_u8: Array,
     s = sub.as_substrate(substrate)
     n = getattr(s.meta, "width", 8)
     px = to_signed_pixels(imgs_u8, n)
-    raw = conv2d_batched(px, jnp.asarray(LAPLACIAN), s,
+    # numpy taps stay concrete under an outer jit: Pallas substrates fuse
+    raw = conv2d_batched(px, LAPLACIAN, s,
                          partitioning=partitioning, site=EDGE_SITE)
     return jnp.clip(_rescale_raw(raw, n), 0, 255).astype(jnp.uint8)
 

@@ -14,7 +14,8 @@ substrate. Padding preserves this because
 * images are zero-embedded at the top-left of the bucket shape, which is
   indistinguishable (to the 'same'-convolution taps of every kept pixel)
   from the zero border padding the direct path applies, and
-* every substrate contraction is row-independent over the im2col matrix
+* every output pixel depends on its own 3×3 window alone, on the fused
+  conv kernel that Pallas substrates run and in every im2col contraction
   (one row per output pixel), so extra pad rows/images never perturb kept
   pixels. Results are cropped back to the request shape.
 
@@ -118,6 +119,11 @@ class EdgeDetectService:
         self.pad_batches = pad_batches
         self.device_latency_s = device_latency_s
         self.partitioning = partitioning
+        # the conv kernel every batch runs, known before the first trace
+        # (plans contract their tap groups through im2col)
+        fused = self.plan is None and conv.takes_fused_path(
+            self.substrate, conv.LAPLACIAN, partitioning)
+        self.conv_path = "fused" if fused else "im2col"
         self.metrics = metrics or ServingMetrics()
         self._compiled_keys = set()  # (batch, H, W) shapes traced so far
         self._compiled_lock = threading.Lock()  # workers race on new shapes
@@ -185,6 +191,8 @@ class EdgeDetectService:
                 h, w = im.shape
                 batch[i, :h, :w] = im
         shape = "x".join(map(str, batch.shape))
+        if self.conv_path == "fused":
+            self.metrics.record_fused_batch()
         with self._compiled_lock:
             first = batch.shape not in self._compiled_keys
             if first:
@@ -194,11 +202,11 @@ class EdgeDetectService:
             # first call for this shape: the jitted call traces + compiles
             # before dispatching, so this span is compile-dominated
             with trace_span("edge.compile", "serving", shape=shape,
-                            spec=self.spec):
+                            spec=self.spec, conv=self.conv_path):
                 out = self._jit_fn(batch)
         else:
             with trace_span("edge.execute", "serving", shape=shape,
-                            spec=self.spec):
+                            spec=self.spec, conv=self.conv_path):
                 out = self._jit_fn(batch)
         return out, [im.shape for im in imgs]
 
@@ -206,7 +214,8 @@ class EdgeDetectService:
         """Delivery phase: block until the dispatched batch is ready and on
         the host, then crop each map back to its request shape. Under a
         tracer the copy is timed apart as ``edge.fetch``; untraced, one
-        ``np.asarray`` waits and copies."""
+        ``np.asarray`` waits and copies. Each map is copied out of the
+        batch array, so a kept map does not hold the whole batch."""
         out_dev, shapes = raw
         with trace_span("edge.wait", "serving", size=len(shapes)):
             if current_tracer() is None:
@@ -219,7 +228,7 @@ class EdgeDetectService:
                                 bytes=out_dev.nbytes, size=len(shapes)):
                     out = np.asarray(out_dev)
         with trace_span("edge.crop", "serving", size=len(shapes)):
-            return [out[i, :h, :w] for i, (h, w) in enumerate(shapes)]
+            return [out[i, :h, :w].copy() for i, (h, w) in enumerate(shapes)]
 
     @staticmethod
     def _check_image(img) -> np.ndarray:

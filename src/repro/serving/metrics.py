@@ -66,6 +66,8 @@ class ServingMetrics:
                                   ("reason",))
         self._compiles = r.counter("serving_compiled_calls_total",
                                    "XLA compilations triggered (new shapes)")
+        self._fused = r.counter("serving_edge_fused_batches_total",
+                                "edge batches run on the fused conv kernel")
         self._depth = r.gauge("serving_queue_depth",
                               "requests waiting in the batcher queue")
         self._depth_peak = r.gauge("serving_queue_depth_peak",
@@ -110,7 +112,7 @@ class ServingMetrics:
             self._latencies: list[float] = []          # seconds, reservoir
             self._latency_count = 0
         for fam in (self._enqueued, self._served, self._failed, self._batches,
-                    self._compiles, self._depth, self._depth_peak,
+                    self._compiles, self._fused, self._depth, self._depth_peak,
                     self._batch_sizes, self._slots_used, self._slots_total,
                     self._latency, self._worker_batches, self._worker_busy,
                     self._worker_errors, self._inflight, self._inflight_peak):
@@ -147,6 +149,9 @@ class ServingMetrics:
 
     def record_compile(self) -> None:
         self._compiles.inc()
+
+    def record_fused_batch(self) -> None:
+        self._fused.inc()
 
     def record_worker_batch(self, worker: str, busy_s: float) -> None:
         """One batch served end-to-end by ``worker`` in ``busy_s`` seconds."""
@@ -189,6 +194,10 @@ class ServingMetrics:
     @property
     def compiled_calls(self) -> int:
         return int(self._compiles.value())
+
+    @property
+    def fused_batches(self) -> int:
+        return int(self._fused.value())
 
     @property
     def queue_depth(self) -> int:
@@ -264,6 +273,7 @@ class ServingMetrics:
             "batches_by_reason": dict(sorted(
                 self.batches_by_reason.items())),
             "compiled_calls": self.compiled_calls,
+            "fused_batches": self.fused_batches,
             "queue_depth": self.queue_depth,
             "queue_depth_peak": self.queue_depth_peak,
             "occupancy_hist": dict(sorted(self.occupancy_hist.items())),

@@ -247,6 +247,64 @@ def test_fused_conv_edge_detect_batched_parity():
     np.testing.assert_array_equal(got, ref)
 
 
+def _pallas_kernels(fn, *args):
+    """Names of the ``pallas_call``s that ``fn`` lowers to under a jit."""
+    names = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(str(eqn.params["name"]))
+            else:
+                for inner in jax.core.jaxprs_in_params(eqn.params):
+                    walk(inner)
+
+    walk(jax.make_jaxpr(jax.jit(fn))(*args).jaxpr)
+    return names
+
+
+@pytest.mark.parametrize("spec,partitioned,kernels", [
+    ("approx_pallas:proposed@4", False, ["fused_conv"]),
+    ("approx_bitexact:proposed@4", False, []),
+    ("approx_pallas:proposed@4", True, ["closed_form_matmul"]),
+])
+def test_jitted_edge_detect_picks_its_conv_kernel(spec, partitioned, kernels):
+    """Under a jit, as the edge service calls it, the Laplacian's numpy
+    taps stay concrete: a Pallas substrate runs the fused conv kernel and
+    builds no patch matrix for ``closed_form_matmul``. A substrate without
+    a fused kernel, and a partitioned contraction, keep im2col. Both
+    paths give the eager pipeline's maps."""
+    from repro.launch.mesh import make_mesh
+
+    part = (sub.Partitioning(make_mesh((1,), ("data",)), m_axis="data")
+            if partitioned else None)
+    imgs = RNG.integers(0, 256, (2, 16, 20)).astype(np.uint8)
+    run = lambda x: conv.edge_detect_batched(x, spec, partitioning=part)  # noqa: E731
+    assert _pallas_kernels(run, imgs) == kernels
+    assert conv.takes_fused_path(sub.get_substrate(spec), conv.LAPLACIAN,
+                                 part) is (kernels == ["fused_conv"])
+    want = conv.edge_detect_batched(imgs, "approx_bitexact:proposed@4")
+    np.testing.assert_array_equal(np.asarray(jax.jit(run)(imgs)),
+                                  np.asarray(want))
+
+
+def test_fused_path_needs_concrete_taps():
+    """``jnp.asarray`` of the taps inside a jit is a tracer: im2col."""
+    s = sub.get_substrate("approx_pallas:proposed@4")
+    seen = []
+
+    @jax.jit
+    def run(x):
+        seen.append((conv.takes_fused_path(s, conv.LAPLACIAN),
+                     conv.takes_fused_path(s, jnp.asarray(conv.LAPLACIAN))))
+        return x
+
+    run(jnp.zeros(2))
+    assert seen == [(True, False)]
+    assert conv.takes_fused_path(s, [[0, 1, 0], [1, -4, 1], [0, 1, 0]])
+    assert not conv.takes_fused_path(s, conv.LAPLACIAN, partitioning=object())
+
+
 def test_fused_true_requires_fused_capable_substrate():
     imgs = _img(6, 6)[None]
     with pytest.raises(ValueError, match="no fused conv"):

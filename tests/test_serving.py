@@ -396,6 +396,54 @@ def test_edge_service_delivery_splits_the_copy_only_when_traced():
     assert fetch["ts"] + fetch["dur"] <= wait["ts"] + wait["dur"]
 
 
+def test_edge_service_delivered_maps_own_their_memory():
+    """A delivered map is copied out of its batch array, full-width crops
+    too, so a map the caller keeps does not hold the whole batch."""
+    svc = EdgeDetectService("exact", start=False)
+    a = np.arange(48, dtype=np.uint8).reshape(3, 4, 4)
+    shapes = [(4, 4), (3, 4), (2, 3)]
+    for out, (h, w), full in zip(
+            svc._finalize((16, 16), (_DeviceValue(a), shapes)), shapes, a):
+        np.testing.assert_array_equal(out, full[:h, :w])
+        assert not np.shares_memory(out, a)
+
+
+@pytest.mark.parametrize("spec,path", [("approx_pallas", "fused"),
+                                       ("approx_bitexact", "im2col")])
+def test_edge_service_reports_its_conv_path(spec, path):
+    """The service knows its conv kernel before the first batch, names it
+    on the ``edge.compile``/``edge.execute`` spans and counts the batches
+    the fused kernel serves."""
+    from repro.obs.trace import Tracer, tracing_scope
+
+    imgs = image_batch(4, 12, 10, seed=3)
+    tracer = Tracer()
+    with tracing_scope(tracer), EdgeDetectService(
+            spec, max_batch_size=2, max_wait_s=1e-3) as svc:
+        assert svc.conv_path == path
+        svc.detect(list(imgs))
+        svc.detect(list(imgs[:2]))
+    spans = [e for e in tracer.events()
+             if e["name"] in ("edge.compile", "edge.execute")]
+    assert len(spans) == svc.metrics.batches_flushed >= 3
+    assert {e["args"]["conv"] for e in spans} == {path}
+    want = svc.metrics.batches_flushed if path == "fused" else 0
+    assert svc.metrics.fused_batches == want
+    assert svc.stats()["fused_batches"] == want
+
+
+def test_edge_service_plan_and_partitioning_take_im2col():
+    from repro.launch.mesh import make_mesh
+    from repro.nn.plan import SubstratePlan
+
+    part = sub.Partitioning(make_mesh((1,), ("data",)), m_axis="data")
+    plan = SubstratePlan(default="approx_pallas:proposed@8")
+    for svc in (EdgeDetectService("approx_pallas", partitioning=part,
+                                  start=False),
+                EdgeDetectService(plan, start=False)):
+        assert svc.conv_path == "im2col"
+
+
 def test_edge_service_rejects_bad_inputs():
     svc = EdgeDetectService("exact", start=False)
     with pytest.raises(ValueError, match="uint8"):

@@ -10,15 +10,17 @@ proposed@8 and for an approximate wiring at width 6, whose LUT is padded
 from 64 to 128 lanes and whose fused-conv table columns emit
 compare-selects.
 
-The wrapper cases (the planned edge path and the per-pixel tap dot) go
-through the ops wrappers, whose padding and orientation decide the HBM
-footprint: they must fit the 16 GB of one v5e chip.
+The wrapper cases (the planned edge path, the per-pixel tap dot and the
+edge service's compiled call) go through the ops wrappers, whose padding
+and orientation decide the HBM footprint: they must fit the 16 GB of one
+v5e chip.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +38,7 @@ from repro.kernels.fused_conv.ops import lut_tap_product
 from repro.kernels.lut_matmul.kernel import lut_matmul_pallas
 from repro.nn import conv
 from repro.nn.plan import SubstratePlan
+from repro.serving import EdgeDetectService
 
 M, K, N = 8, 4096, 16384
 FRAMES, H, W = 8, 1088, 1920
@@ -71,6 +74,13 @@ def _compile(fn, *shapes):
     compiled = jax.jit(fn).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text()
     return compiled
+
+
+def _kernels(compiled) -> set:
+    """Names of the Mosaic kernels in the compiled program."""
+    return set(re.findall(r"%([A-Za-z_]\w*?)(?:\.\d+)? = .*"
+                          r'custom_call_target="tpu_custom_call"',
+                          compiled.as_text()))
 
 
 def _hbm_bytes(compiled) -> int:
@@ -121,6 +131,7 @@ def test_tap_dot_fits_hbm_at_1080p(one_chip, mosaic):
         fn, jax.ShapeDtypeStruct((FRAMES * H * W, 9), jnp.int32,
                                  sharding=one_chip),
         jax.ShapeDtypeStruct((9, 1), jnp.int32, sharding=one_chip))
+    assert _kernels(compiled) == {"closed_form_matmul"}
     assert _hbm_bytes(compiled) < HBM_BYTES
 
 
@@ -132,4 +143,17 @@ def test_planned_edge_fits_hbm_at_1080p(one_chip, mosaic):
     compiled = _compile(
         lambda x: conv.edge_detect_planned(x, plan),
         jax.ShapeDtypeStruct((FRAMES, H, W), jnp.uint8, sharding=one_chip))
+    assert _hbm_bytes(compiled) < HBM_BYTES
+
+
+def test_edge_service_call_runs_fused_conv_at_1080p(one_chip, mosaic):
+    """The service's own compiled call on a batch of eight 1080p frames
+    runs the fused conv kernel, builds no im2col patch matrix for
+    ``closed_form_matmul``, and fits one chip."""
+    svc = EdgeDetectService("approx_pallas", start=False)
+    assert svc.conv_path == "fused"
+    compiled = _compile(
+        svc._jit_fn,
+        jax.ShapeDtypeStruct((FRAMES, H, W), jnp.uint8, sharding=one_chip))
+    assert _kernels(compiled) == {"fused_conv"}
     assert _hbm_bytes(compiled) < HBM_BYTES
