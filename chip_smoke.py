@@ -15,8 +15,9 @@ Phases, in order:
 4. LUT strategy: ``approx_pallas:exact`` served against ``exact``; the
    LUT matmul kernel (proposed@8 and design_du2022@6) and the fused
    conv's LUT kind (design_du2022@6) against ``approx_bitexact``;
-5. LM serving: minitron-8b at its published widths, cut to 4 layers and a
-   1/8 vocab, serving requests under ``exact`` and ``approx_pallas``;
+5. LM serving: minitron-8b's published block and widths (the full 256000
+   vocabulary, an untied head), cut to 4 layers, serving requests under
+   ``exact`` and ``approx_pallas``;
 6. kernel check: ``closed_form_matmul`` at a layer shape against
    ``approx_bitexact``.
 
@@ -248,9 +249,10 @@ def phase_lm() -> None:
     from repro.serving import ServingEngine
     from repro.serving.engine import Request
 
-    # minitron-8b at its published widths (d_model 4096, d_ff 16384, GQA
-    # 32/8, bf16), cut to 4 of 32 layers and 32000 of 256000 vocab rows
-    cfg = reg.get_config("minitron-8b", n_layers=4, vocab=32000)
+    # minitron-8b's Nemotron block at its published widths (d_model 4096,
+    # 48/8 heads of 128, squared-ReLU d_ff 16384, untied 256000-wide head,
+    # bf16), cut to 4 of 32 layers
+    cfg = reg.get_config("minitron-8b", n_layers=4)
     bundle = reg._BUILDERS[cfg.family](cfg)
     params, dt = timed(jax.jit(bundle.init_params), jax.random.PRNGKey(SEED))
     n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))

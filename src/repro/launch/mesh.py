@@ -92,6 +92,7 @@ _RULES = [
     (("out_proj", "w"), P("model", _FSDP)),
     (("patch_proj", "w"), P(_FSDP, "model")),
     (("emb",), P("model", _FSDP)),
+    (("head", "w"), P(_FSDP, "model")),
 ]
 
 

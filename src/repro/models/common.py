@@ -61,6 +61,12 @@ class ModelConfig:
     local_window: int = 0          # sliding-window size for local layers
     local_global_ratio: int = 0    # e.g. 5 -> 5 local : 1 global
     rope_theta: float = 1e4
+    rotary_pct: float = 1.0        # share of each head's dims RoPE rotates
+    # block
+    mlp: str = "swiglu"            # swiglu | relu2 (non-gated squared ReLU)
+    norm: str = "rms"              # rms | layernorm1p (LN(x)·(1+γ)+β)
+    norm_eps: float = 1e-6
+    tie_embeddings: bool = True    # False: a separate lm_head dense() site
     # SSM / recurrent
     ssm_state: int = 0
     conv_width: int = 4
@@ -71,7 +77,9 @@ class ModelConfig:
     # encoder (enc-dec only)
     n_encoder_layers: int = 0
     # execution
-    dtype: Any = jnp.bfloat16
+    dtype: Any = jnp.bfloat16      # weights and caches
+    act_dtype: Any = None          # activations from the embedding on
+                                   # (None → dtype)
     dot_mode: str = "exact"        # DEPRECATED single substrate spec
                                    # "backend[:mult_name]"; kept as the
                                    # uniform-plan shim — prefer dot_plan
@@ -99,7 +107,7 @@ class ModelConfig:
         d, v = self.d_model, self.vocab
         attn = d * self.n_heads * self.dh + 2 * d * self.n_kv_heads * self.dh \
             + self.n_heads * self.dh * d
-        dense_ffn = 3 * d * self.d_ff
+        dense_ffn = (2 if self.mlp == "relu2" else 3) * d * self.d_ff
         emb = v * d
         if self.family == "xlstm":
             per_layer = 8 * d * d // 2  # m/sLSTM projections (approx.)
@@ -114,7 +122,8 @@ class ModelConfig:
         moe_ffn = n_moe * (self.n_experts * 3 * d * self.d_ff_expert
                            + d * self.n_experts
                            + (3 * d * self.d_ff_expert if self.shared_expert else 0))
-        total = self.n_layers * attn + n_dense * dense_ffn + moe_ffn + emb
+        total = self.n_layers * attn + n_dense * dense_ffn + moe_ffn \
+            + emb * (1 if self.tie_embeddings else 2)
         if self.family == "encdec":
             total += self.n_encoder_layers * (attn + dense_ffn + attn)  # + cross-attn
         return total
@@ -229,6 +238,32 @@ def rms_norm(x: Array, scale: Array, eps: float = 1e-6) -> Array:
     return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
+def layer_norm_1p(x: Array, gamma: Array, beta: Array,
+                  eps: float = 1e-5) -> Array:
+    """Nemotron's LayerNorm1p: ``LN(x)·(1+γ)+β``, in float32."""
+    xf = x.astype(jnp.float32)
+    mu = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
+    y = (xf - mu) * jax.lax.rsqrt(var + eps) * (1.0 + gamma) + beta
+    return y.astype(x.dtype)
+
+
+def init_norm(cfg: ModelConfig, name: str = "ln") -> Params:
+    """The norm's parameters under ``name`` (and ``name + "_b"``)."""
+    d = cfg.d_model
+    if cfg.norm == "layernorm1p":
+        return {name: jnp.zeros((d,), jnp.float32),
+                f"{name}_b": jnp.zeros((d,), jnp.float32)}
+    return {name: jnp.ones((d,), jnp.float32)}
+
+
+def norm(cfg: ModelConfig, x: Array, p: Params, name: str = "ln") -> Array:
+    """The config's norm (``cfg.norm``) with the parameters under ``name``."""
+    if cfg.norm == "layernorm1p":
+        return layer_norm_1p(x, p[name], p[f"{name}_b"], cfg.norm_eps)
+    return rms_norm(x, p[name], cfg.norm_eps)
+
+
 def init_dense(key, d_in: int, d_out: int, dtype, bias: bool = False) -> Params:
     w = jax.random.normal(key, (d_in, d_out), dtype=jnp.float32) / math.sqrt(d_in)
     p = {"w": w.astype(dtype)}
@@ -242,18 +277,21 @@ def init_dense(key, d_in: int, d_out: int, dtype, bias: bool = False) -> Params:
 # ---------------------------------------------------------------------------
 
 
-def rope(x: Array, positions: Array, theta: float) -> Array:
-    """x: (B, S, H, dh); positions: (B, S) int32."""
+def rope(x: Array, positions: Array, theta: float,
+         rotary_pct: float = 1.0) -> Array:
+    """x: (B, S, H, dh); positions: (B, S) int32. Rotates the first
+    ``rotary_pct·dh`` dims of each head (rounded down to even; frequencies
+    over that width) and passes the rest through."""
     dh = x.shape[-1]
-    half = dh // 2
+    half = int(dh * rotary_pct) // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B, S, half)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
     x1, x2 = x[..., :half], x[..., half:2 * half]
     rot = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    if dh % 2:
-        rot = jnp.concatenate([rot, x[..., -1:]], axis=-1)
+    if 2 * half < dh:
+        rot = jnp.concatenate([rot, x[..., 2 * half:]], axis=-1)
     return rot.astype(x.dtype)
 
 
@@ -347,7 +385,7 @@ def init_attn(key, cfg: ModelConfig) -> Params:
         "wk": init_dense(ks[1], d, hkv * dh, cfg.dtype, cfg.qkv_bias),
         "wv": init_dense(ks[2], d, hkv * dh, cfg.dtype, cfg.qkv_bias),
         "wo": init_dense(ks[3], h * dh, d, cfg.dtype),
-        "ln": jnp.ones((d,), jnp.float32),
+        **init_norm(cfg),
     }
 
 
@@ -364,7 +402,7 @@ def attn_block(cfg: ModelConfig, p: Params, x: Array, *, positions: Array,
     """
     b, s, d = x.shape
     h, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.dh
-    xn = rms_norm(x, p["ln"])
+    xn = norm(cfg, x, p)
     with splan.site_scope("attn"):
         q = dense(cfg, xn, p["wq"]["w"], p["wq"].get("b"),
                   site="wq").reshape(b, s, h, dh)
@@ -373,8 +411,8 @@ def attn_block(cfg: ModelConfig, p: Params, x: Array, *, positions: Array,
                       site="wk").reshape(b, s, hkv, dh)
             v = dense(cfg, xn, p["wv"]["w"], p["wv"].get("b"),
                       site="wv").reshape(b, s, hkv, dh)
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
+            q = rope(q, positions, cfg.rope_theta, cfg.rotary_pct)
+            k = rope(k, positions, cfg.rope_theta, cfg.rotary_pct)
         else:
             k, v = cross_kv
 
@@ -404,26 +442,33 @@ def attn_block(cfg: ModelConfig, p: Params, x: Array, *, positions: Array,
 
 
 # ---------------------------------------------------------------------------
-# Dense (SwiGLU) FFN
+# Dense FFN: SwiGLU, or the non-gated squared ReLU (cfg.mlp == "relu2")
 # ---------------------------------------------------------------------------
 
 
 def init_ffn(key, cfg: ModelConfig, d_ff: Optional[int] = None) -> Params:
     ks = jax.random.split(key, 3)
     d, f = cfg.d_model, d_ff or cfg.d_ff
-    return {
+    p = {
         "wi": init_dense(ks[0], d, f, cfg.dtype),
-        "wg": init_dense(ks[1], d, f, cfg.dtype),
         "wo": init_dense(ks[2], f, d, cfg.dtype),
-        "ln": jnp.ones((d,), jnp.float32),
+        **init_norm(cfg),
     }
+    if cfg.mlp != "relu2":
+        p["wg"] = init_dense(ks[1], d, f, cfg.dtype)
+    return p
 
 
 def ffn_block(cfg: ModelConfig, p: Params, x: Array) -> Array:
-    xn = rms_norm(x, p["ln"])
+    xn = norm(cfg, x, p)
     with splan.site_scope("ffn"):
-        hidden = (jax.nn.silu(dense(cfg, xn, p["wg"]["w"], site="wg"))
-                  * dense(cfg, xn, p["wi"]["w"], site="wi"))
+        if cfg.mlp == "relu2":
+            up = dense(cfg, xn, p["wi"]["w"], site="wi")
+            hidden = jnp.square(jax.nn.relu(up.astype(jnp.float32))
+                                ).astype(up.dtype)
+        else:
+            hidden = (jax.nn.silu(dense(cfg, xn, p["wg"]["w"], site="wg"))
+                      * dense(cfg, xn, p["wi"]["w"], site="wi"))
         hidden = sh.constrain(hidden, "batch", "seq", "mlp")
         return x + dense(cfg, hidden, p["wo"]["w"], site="wo").astype(x.dtype)
 
@@ -442,7 +487,7 @@ def init_moe(key, cfg: ModelConfig) -> Params:
         "wi": (jax.random.normal(ks[1], (e, d, f), jnp.float32) * std).astype(cfg.dtype),
         "wg": (jax.random.normal(ks[2], (e, d, f), jnp.float32) * std).astype(cfg.dtype),
         "wo": (jax.random.normal(ks[3], (e, f, d), jnp.float32) / math.sqrt(f)).astype(cfg.dtype),
-        "ln": jnp.ones((d,), jnp.float32),
+        **init_norm(cfg),
     }
     if cfg.shared_expert:
         p["shared"] = init_ffn(ks[4], cfg, cfg.d_ff_expert)
@@ -525,7 +570,7 @@ def _expert_ffn(p: Params, buf: Array) -> Array:
 def _moe_block_local(cfg: ModelConfig, p: Params, x: Array) -> Array:
     b, s, d = x.shape
     t = b * s
-    xn = rms_norm(x, p["ln"]).reshape(t, d)
+    xn = norm(cfg, x, p).reshape(t, d)
     buf, info = _dispatch_local(cfg, xn, p["router"])
     out = _expert_ffn(p, buf)
     y = _combine_local(out, info, t)
@@ -553,7 +598,7 @@ def _moe_block_ep(cfg: ModelConfig, p: Params, x: Array, mesh, dp) -> Array:
 
     b, s, d = x.shape
     t = b * s
-    xn = rms_norm(x, p["ln"]).reshape(t, d)
+    xn = norm(cfg, x, p).reshape(t, d)
     m_size = mesh.shape["model"]
 
     def body(xn_l, router, wi_l, wg_l, wo_l):
@@ -588,34 +633,49 @@ def _moe_block_ep(cfg: ModelConfig, p: Params, x: Array, mesh, dp) -> Array:
 
 
 # ---------------------------------------------------------------------------
-# Embedding / LM head / chunked loss
+# Embedding / LM head (tied einsum or untied dense()) / chunked loss
 # ---------------------------------------------------------------------------
 
 
 def init_embed(key, cfg: ModelConfig) -> Params:
-    emb = jax.random.normal(key, (cfg.vocab, cfg.d_model), jnp.float32)
-    return {"emb": (emb / math.sqrt(cfg.d_model)).astype(cfg.dtype),
-            "ln_f": jnp.ones((cfg.d_model,), jnp.float32)}
+    """Embedding, final norm, and (untied configs) the output head."""
+    k_emb = key
+    if not cfg.tie_embeddings:
+        k_emb, k_head = jax.random.split(key)
+    emb = jax.random.normal(k_emb, (cfg.vocab, cfg.d_model), jnp.float32)
+    p = {"emb": (emb / math.sqrt(cfg.d_model)).astype(cfg.dtype),
+         **init_norm(cfg, "ln_f")}
+    if not cfg.tie_embeddings:
+        p["head"] = init_dense(k_head, cfg.d_model, cfg.vocab, cfg.dtype)
+    return p
 
 
 def embed(cfg: ModelConfig, p: Params, tokens: Array) -> Array:
     e = sh.constrain(p["emb"], "vocab", "embed")
     x = e[tokens]
+    if cfg.act_dtype is not None:
+        x = x.astype(cfg.act_dtype)
     return sh.constrain(x, "batch", "seq", "embed")
 
 
 def lm_loss_chunked(cfg: ModelConfig, p: Params, x: Array, labels: Array) -> Array:
     """Streaming softmax-xent: never materializes (B, S, V) at once."""
     b, s, d = x.shape
-    x = rms_norm(x, p["ln_f"])
+    x = norm(cfg, x, p, "ln_f")
     chunk = min(cfg.loss_chunk, s)
     n = s // chunk
-    emb_t = p["emb"].astype(jnp.float32).T  # (d, V)
+    emb_t = p["emb"].astype(jnp.float32).T if cfg.tie_embeddings else None
+
+    def head(xs):
+        if emb_t is None:
+            return dense(cfg, xs.astype(jnp.float32), p["head"]["w"],
+                         site="lm_head")
+        return jnp.einsum("bsd,dv->bsv", xs.astype(jnp.float32), emb_t)
 
     def body(acc, idx):
         xs = jax.lax.dynamic_slice_in_dim(x, idx * chunk, chunk, axis=1)
         ls = jax.lax.dynamic_slice_in_dim(labels, idx * chunk, chunk, axis=1)
-        logits = jnp.einsum("bsd,dv->bsv", xs.astype(jnp.float32), emb_t)
+        logits = head(xs)
         logits = sh.constrain(logits, "batch", "seq", "vocab")
         logz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, ls[..., None], axis=-1)[..., 0]
@@ -626,7 +686,7 @@ def lm_loss_chunked(cfg: ModelConfig, p: Params, x: Array, labels: Array) -> Arr
                             unroll=n if cfg.cost_unroll else 1)
     rem = s - n * chunk
     if rem:
-        logits = jnp.einsum("bsd,dv->bsv", x[:, n * chunk:].astype(jnp.float32), emb_t)
+        logits = head(x[:, n * chunk:])
         logz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, labels[:, n * chunk:][..., None], -1)[..., 0]
         total = total + (logz - gold).sum()
@@ -634,7 +694,13 @@ def lm_loss_chunked(cfg: ModelConfig, p: Params, x: Array, labels: Array) -> Arr
 
 
 def lm_logits(cfg: ModelConfig, p: Params, x: Array) -> Array:
-    x = rms_norm(x, p["ln_f"])
-    logits = jnp.einsum("bsd,vd->bsv", x.astype(jnp.float32),
-                        p["emb"].astype(jnp.float32))
+    """Float32 logits: the tied embedding's einsum, or the untied head as
+    the ``dense()`` site ``lm_head``, under the plan like any projection."""
+    x = norm(cfg, x, p, "ln_f")
+    if cfg.tie_embeddings:
+        logits = jnp.einsum("bsd,vd->bsv", x.astype(jnp.float32),
+                            p["emb"].astype(jnp.float32))
+    else:
+        logits = dense(cfg, x.astype(jnp.float32), p["head"]["w"],
+                       site="lm_head")
     return sh.constrain(logits, "batch", "seq", "vocab")

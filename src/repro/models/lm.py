@@ -2,8 +2,9 @@
 
 Covers: llama4-maverick (interleaved MoE top-1 + shared expert), kimi-k2
 (all-MoE top-8 + shared expert), internlm2, qwen1.5 (qkv bias), gemma3
-(5:1 local:global attention), minitron, and the paligemma VLM backbone
-(prefix patch embeddings).
+(5:1 local:global attention), minitron (the Nemotron block: LayerNorm1p,
+half-width RoPE, squared-ReLU MLP, untied ``lm_head``), and the paligemma
+VLM backbone (prefix patch embeddings).
 
 Layers are grouped into a repeating *unit* (period = lcm of the MoE
 interleave and the local:global pattern); params are stacked over unit

@@ -14,8 +14,9 @@ Site names
 Sites are dotted paths pushed by the model code (:func:`site_scope`) around
 each :func:`repro.models.common.dense` / conv contraction:
 
-* LM / VLM:   ``layer.{i}.attn.{wq,wk,wv,wo}``, ``layer.{i}.ffn.{wg,wi,wo}``,
-  ``layer.{i}.moe.shared.{…}``, ``patch_proj``
+* LM / VLM:   ``layer.{i}.attn.{wq,wk,wv,wo}``, ``layer.{i}.ffn.{wg,wi,wo}``
+  (``wi``/``wo`` only for the squared-ReLU MLP), ``layer.{i}.moe.shared.{…}``,
+  ``patch_proj``, ``lm_head`` (an untied output head)
 * enc-dec:    ``enc.{i}.attn.*``, ``dec.{i}.self.attn.*``,
   ``dec.{i}.cross.attn.*``, ``dec.{i}.cross.{wk,wv}``, ``dec.{i}.ffn.*``
 * xLSTM:      ``layer.{i}.{mlstm,slstm}.{wq,…,wo}``

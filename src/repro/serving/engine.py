@@ -7,7 +7,11 @@ until EOS/max_tokens. Per-slot state lives in the model's KV caches; the
 queue/slot-refill bookkeeping is the shared
 :class:`~repro.serving.batcher.SlotScheduler` (the same scheduling core the
 vision micro-batcher builds on), and per-step occupancy plus per-request
-latency land in a :class:`~repro.serving.metrics.ServingMetrics`.
+latency land in a :class:`~repro.serving.metrics.ServingMetrics`, which
+also counts the prompt tokens fed (``serving_lm_prompt_tokens_total``) and
+the output tokens emitted (``serving_lm_tokens_total``). Each step is a
+``serve.decode_step`` span holding ``lm.fetch`` (the logits' device→host
+copy, split out under a tracer) and ``lm.sample`` (host sampling).
 
 ``generate(requests, workers=N)`` runs N concurrent decode loops, each with
 its *own* KV caches, slot pool, and sampling RNG, all sharing the one
@@ -41,7 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.obs.trace import trace_span
+from repro.obs.trace import current_tracer, trace_span
 from repro.serving.batcher import SlotScheduler
 from repro.serving.metrics import ServingMetrics
 
@@ -126,10 +130,19 @@ class ServingEngine:
         self.state = self._init_state()
 
     def _step(self, state, tokens: np.ndarray, cache_len: int):
+        """One compiled decode step; returns the (batch, vocab) float32
+        logits on the host. Under a tracer the device→host copy is timed
+        apart as ``lm.fetch`` (after the step's device work has finished);
+        untraced, one ``np.asarray`` waits and copies."""
         batch = {"token": jnp.asarray(tokens.reshape(self.batch, 1), jnp.int32),
                  "cache_len": jnp.asarray(cache_len, jnp.int32)}
         logits, state = self._decode(self.params, state, batch)
-        return np.asarray(logits[:, 0, :], np.float32), state
+        if current_tracer() is None:
+            return np.asarray(logits, np.float32)[:, 0, :], state
+        logits.block_until_ready()
+        with trace_span("lm.fetch", "serving", bytes=int(logits.nbytes)):
+            host = np.asarray(logits, np.float32)
+        return host[:, 0, :], state
 
     def _sample(self, logits: np.ndarray, temps: np.ndarray,
                 rng: np.random.Generator) -> np.ndarray:
@@ -208,11 +221,13 @@ class ServingEngine:
             if not sched.occupancy:
                 continue                             # nothing seated this step
             tokens = np.zeros(self.batch, np.int64)
+            n_prompt = n_out = 0
             for i, r in sched.occupied():
                 if r.done:
                     continue
                 if cursor[i] < len(r.prompt):
                     tokens[i] = r.prompt[int(cursor[i])]
+                    n_prompt += 1
                 elif r.output:
                     tokens[i] = r.output[-1]
             self.metrics.record_batch(sched.occupancy, "decode", self.batch)
@@ -221,10 +236,12 @@ class ServingEngine:
                             cache_len=cache_len, occupancy=sched.occupancy,
                             worker=worker):
                 logits, state = self._step(state, tokens, cache_len)
+                with trace_span("lm.sample", "serving"):
+                    temps = np.array([r.temperature if r else 0.0
+                                      for r in sched.slots])
+                    nxt = self._sample(logits, temps, rng)
             self.metrics.record_worker_batch(
                 worker, time.perf_counter() - t_step)
-            temps = np.array([r.temperature if r else 0.0 for r in sched.slots])
-            nxt = self._sample(logits, temps, rng)
             for i, r in sched.occupied():
                 if r.done:
                     continue
@@ -232,6 +249,7 @@ class ServingEngine:
                 if cursor[i] >= len(r.prompt):       # past prefill: emit
                     tok = int(nxt[i])
                     r.output.append(tok)
+                    n_out += 1
                     if (r.eos_id is not None and tok == r.eos_id) or \
                             len(r.output) >= r.max_tokens:
                         r.done = True
@@ -240,6 +258,7 @@ class ServingEngine:
                         self.metrics.record_done(
                             time.perf_counter() - t_start[id(r)],
                             depth=len(sched.queue))
+            self.metrics.record_lm_tokens(n_prompt, n_out)
             cache_len += 1
         for r in requests:
             r.done = True

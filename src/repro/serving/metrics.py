@@ -68,6 +68,10 @@ class ServingMetrics:
                                    "XLA compilations triggered (new shapes)")
         self._fused = r.counter("serving_edge_fused_batches_total",
                                 "edge batches run on the fused conv kernel")
+        self._lm_tokens = r.counter("serving_lm_tokens_total",
+                                    "LM output tokens emitted")
+        self._lm_prompt_tokens = r.counter("serving_lm_prompt_tokens_total",
+                                           "LM prompt tokens fed")
         self._depth = r.gauge("serving_queue_depth",
                               "requests waiting in the batcher queue")
         self._depth_peak = r.gauge("serving_queue_depth_peak",
@@ -112,7 +116,8 @@ class ServingMetrics:
             self._latencies: list[float] = []          # seconds, reservoir
             self._latency_count = 0
         for fam in (self._enqueued, self._served, self._failed, self._batches,
-                    self._compiles, self._fused, self._depth, self._depth_peak,
+                    self._compiles, self._fused, self._lm_tokens,
+                    self._lm_prompt_tokens, self._depth, self._depth_peak,
                     self._batch_sizes, self._slots_used, self._slots_total,
                     self._latency, self._worker_batches, self._worker_busy,
                     self._worker_errors, self._inflight, self._inflight_peak):
@@ -152,6 +157,12 @@ class ServingMetrics:
 
     def record_fused_batch(self) -> None:
         self._fused.inc()
+
+    def record_lm_tokens(self, prompt: int, output: int) -> None:
+        """One LM decode step fed ``prompt`` prompt tokens and emitted
+        ``output`` tokens."""
+        self._lm_prompt_tokens.inc(prompt)
+        self._lm_tokens.inc(output)
 
     def record_worker_batch(self, worker: str, busy_s: float) -> None:
         """One batch served end-to-end by ``worker`` in ``busy_s`` seconds."""
@@ -198,6 +209,14 @@ class ServingMetrics:
     @property
     def fused_batches(self) -> int:
         return int(self._fused.value())
+
+    @property
+    def lm_tokens(self) -> int:
+        return int(self._lm_tokens.value())
+
+    @property
+    def lm_prompt_tokens(self) -> int:
+        return int(self._lm_prompt_tokens.value())
 
     @property
     def queue_depth(self) -> int:
@@ -274,6 +293,8 @@ class ServingMetrics:
                 self.batches_by_reason.items())),
             "compiled_calls": self.compiled_calls,
             "fused_batches": self.fused_batches,
+            "lm_tokens": self.lm_tokens,
+            "lm_prompt_tokens": self.lm_prompt_tokens,
             "queue_depth": self.queue_depth,
             "queue_depth_peak": self.queue_depth_peak,
             "occupancy_hist": dict(sorted(self.occupancy_hist.items())),
