@@ -268,6 +268,7 @@ def phase_lm() -> None:
         def checked_step(state, tokens, cache_len, _step=step):
             t0 = time.perf_counter()
             logits, state = _step(state, tokens, cache_len)
+            jax.numpy.asarray(logits).block_until_ready()
             steps.append(time.perf_counter() - t0)
             check(np.isfinite(logits).all(),
                   f"LM {spec}: non-finite logits at cache_len {cache_len}")

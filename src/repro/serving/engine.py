@@ -1,29 +1,39 @@
-"""Batched LM serving engine: prefill + greedy/temperature decode.
+"""Batched LM serving engine: token-by-token prefill, then decode with
+per-request temperature.
 
-A single-process continuous-batching core: requests are padded into a fixed
-batch, prefilled token-by-token through ``decode_step`` (uniform code path —
-no separate prefill graph to keep per-request state simple), then decoded
-until EOS/max_tokens. Per-slot state lives in the model's KV caches; the
-queue/slot-refill bookkeeping is the shared
-:class:`~repro.serving.batcher.SlotScheduler` (the same scheduling core the
-vision micro-batcher builds on), and per-step occupancy plus per-request
-latency land in a :class:`~repro.serving.metrics.ServingMetrics`, which
+Requests are padded into a fixed batch, prefilled token-by-token through
+``decode_step`` (uniform code path — no separate prefill graph to keep
+per-request state simple), then decoded until EOS/max_tokens. The batch
+shares one ``cache_len``, so it serves waves: a slot refilled from the
+queue mid-wave attends over its previous occupant's cache (below).
+Per-slot state lives in the model's KV caches; the queue/slot-refill
+bookkeeping is the shared :class:`~repro.serving.batcher.SlotScheduler`
+(the same scheduling core the vision micro-batcher builds on), and
+per-step occupancy plus per-request latency land in a
+:class:`~repro.serving.metrics.ServingMetrics`, which
 also counts the prompt tokens fed (``serving_lm_prompt_tokens_total``) and
-the output tokens emitted (``serving_lm_tokens_total``). Each step is a
-``serve.decode_step`` span holding ``lm.fetch`` (the logits' device→host
-copy, split out under a tracer) and ``lm.sample`` (host sampling).
+the output tokens emitted (``serving_lm_tokens_total``).
+
+Sampling runs on the device (:func:`sample_tokens`): a slot with
+temperature <= 0 takes the argmax of its logits, any other draws from
+``softmax(logits / T)`` by Gumbel-max. No sampling runs on the host, and
+the engine copies only the batch's token ids there. Each step is a
+``serve.decode_step`` span holding ``lm.sample`` (the sampler's dispatch
+and its wait) and ``lm.fetch`` (the ids' device→host copy, ``bytes`` =
+4 per slot).
 
 ``generate(requests, workers=N)`` runs N concurrent decode loops, each with
-its *own* KV caches, slot pool, and sampling RNG, all sharing the one
-compiled ``decode_step`` (JAX compiled calls are thread-safe) and the one
-metrics instance (``serving_worker_*`` families labeled ``lm-0..N-1``).
-Requests split round-robin across loops. Greedy decodes of first-wave
-requests (seated into fresh cache lanes) are bit-identical at every worker
-count; a request seated into a *refilled* slot attends over the previous
-occupant's cache prefix, so its tokens depend on scheduling order — a
-pre-existing property of the shared-``cache_len`` engine that holds even
-at ``workers=1`` (reordering requests changes refilled-slot outputs the
-same way).
+its *own* KV caches, slot pool, and sampling key (drawn once from its own
+``np.random.Generator``; each step folds in the step index), all sharing
+the one compiled ``decode_step`` (JAX compiled calls are thread-safe) and
+the one metrics instance (``serving_worker_*`` families labeled
+``lm-0..N-1``). Requests split round-robin across loops. Greedy decodes of
+first-wave requests (seated into fresh cache lanes) are bit-identical at
+every worker count; a request seated into a *refilled* slot attends over
+the previous occupant's cache prefix, so its tokens depend on scheduling
+order — a pre-existing property of the shared-``cache_len`` engine that
+holds even at ``workers=1`` (reordering requests changes refilled-slot
+outputs the same way).
 
 For the large-scale path, the *dry-run* lowers the dedicated ``prefill``
 graph (chunked attention, full-sequence); this engine is the functional
@@ -59,6 +69,50 @@ class Request:
     # filled by the engine
     output: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
+
+
+class DeviceLogits:
+    """A decode step's (batch, vocab) float32 logits, left on the device.
+
+    JAX takes the device array as it is (``jnp.asarray``). Numpy reads a
+    read-only host copy, fetched once and kept by the array
+    (``np.asarray``, ``np.stack``, indexing), so reading column blocks of
+    many steps copies each step once; ``copy()`` is a host array the
+    caller may alter."""
+
+    __slots__ = ("array",)
+
+    def __init__(self, array: jax.Array):
+        self.array = array
+
+    def __jax_array__(self) -> jax.Array:
+        return self.array
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        return np.asarray(self.array, dtype)
+
+    def __getitem__(self, idx) -> np.ndarray:
+        return np.asarray(self.array)[idx]
+
+    def copy(self) -> np.ndarray:
+        return np.array(self.array)
+
+
+@jax.jit
+def sample_tokens(logits: jax.Array, temps: jax.Array, key: jax.Array,
+                  step) -> jax.Array:
+    """(batch,) int32 ids from (batch, vocab) logits, in float32: the
+    argmax (first on ties) where ``temps <= 0``, else an exact draw from
+    ``softmax(logits / temps)`` by Gumbel-max, with ``step`` folded into
+    ``key``."""
+    logits = logits.astype(jnp.float32)
+    temps = temps.astype(jnp.float32)
+    greedy = temps <= 0
+    drawn = jax.random.categorical(
+        jax.random.fold_in(key, step),
+        logits / jnp.where(greedy, 1.0, temps)[:, None], axis=-1)
+    return jnp.where(greedy, jnp.argmax(logits, axis=-1),
+                     drawn).astype(jnp.int32)
 
 
 class ServingEngine:
@@ -116,7 +170,12 @@ class ServingEngine:
         self.seed = seed
         self.rng = np.random.default_rng(seed)
         self.metrics = metrics if metrics is not None else ServingMetrics()
-        self._decode = jax.jit(bundle.decode_step)
+
+        def decode_step(params, state, batch):
+            logits, state = bundle.decode_step(params, state, batch)
+            return logits[:, 0, :].astype(jnp.float32), state
+
+        self._decode = jax.jit(decode_step)
         self._reset_state()
 
     def _init_state(self):
@@ -131,32 +190,15 @@ class ServingEngine:
 
     def _step(self, state, tokens: np.ndarray, cache_len: int):
         """One compiled decode step; returns the (batch, vocab) float32
-        logits on the host. Under a tracer the device→host copy is timed
-        apart as ``lm.fetch`` (after the step's device work has finished);
-        untraced, one ``np.asarray`` waits and copies."""
+        logits as :class:`DeviceLogits`, left on the device. Untraced it
+        does not wait; under a tracer it waits for the step's device work,
+        so the spans after it hold host time only."""
         batch = {"token": jnp.asarray(tokens.reshape(self.batch, 1), jnp.int32),
                  "cache_len": jnp.asarray(cache_len, jnp.int32)}
         logits, state = self._decode(self.params, state, batch)
-        if current_tracer() is None:
-            return np.asarray(logits, np.float32)[:, 0, :], state
-        logits.block_until_ready()
-        with trace_span("lm.fetch", "serving", bytes=int(logits.nbytes)):
-            host = np.asarray(logits, np.float32)
-        return host[:, 0, :], state
-
-    def _sample(self, logits: np.ndarray, temps: np.ndarray,
-                rng: np.random.Generator) -> np.ndarray:
-        out = np.empty(self.batch, np.int64)
-        for i in range(self.batch):
-            if temps[i] <= 0:
-                out[i] = logits[i].argmax()
-            else:
-                z = logits[i] / temps[i]
-                z -= z.max()
-                p = np.exp(z)
-                p /= p.sum()
-                out[i] = rng.choice(len(p), p=p)
-        return out
+        if current_tracer() is not None:
+            logits.block_until_ready()
+        return DeviceLogits(logits), state
 
     def generate(self, requests: List[Request],
                  workers: int = 1) -> List[Request]:
@@ -211,6 +253,7 @@ class ServingEngine:
         cache_len = 0
         served: set = set()                           # id(r) with metrics
         state = self._init_state()                    # this loop's KV caches
+        key = jax.random.PRNGKey(int(rng.integers(2**31)))  # this loop's draws
         cursor = np.zeros(self.batch, np.int64)       # prompt cursor
         while sched.busy and cache_len < self.max_len - 1:
             for i, r in sched.refill():
@@ -236,10 +279,16 @@ class ServingEngine:
                             cache_len=cache_len, occupancy=sched.occupancy,
                             worker=worker):
                 logits, state = self._step(state, tokens, cache_len)
+                temps = np.array([r.temperature if r else 0.0
+                                  for r in sched.slots], np.float32)
                 with trace_span("lm.sample", "serving"):
-                    temps = np.array([r.temperature if r else 0.0
-                                      for r in sched.slots])
-                    nxt = self._sample(logits, temps, rng)
+                    # jnp.asarray, not .array: a wrapped _step may hand
+                    # back host logits
+                    ids = sample_tokens(jnp.asarray(logits), temps, key,
+                                        cache_len)
+                    ids.block_until_ready()
+                with trace_span("lm.fetch", "serving", bytes=int(ids.nbytes)):
+                    nxt = np.asarray(ids)
             self.metrics.record_worker_batch(
                 worker, time.perf_counter() - t_step)
             for i, r in sched.occupied():

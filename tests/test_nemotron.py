@@ -92,7 +92,7 @@ def test_engine_spans_and_token_counters():
         spans = [e for e in ev if e["name"] == name]
         assert len(spans) == len(steps) == 6        # 3 prompt + 3 more steps
         assert all(e["args"]["parent"] == "serve.decode_step" for e in spans)
-    assert all(e["args"]["bytes"] == 2 * 512 * 4
+    assert all(e["args"]["bytes"] == 2 * 4           # the ids, not logits
                for e in ev if e["name"] == "lm.fetch")
     eng.metrics.reset()
     assert eng.metrics.lm_tokens == eng.metrics.lm_prompt_tokens == 0

@@ -12,6 +12,7 @@ from repro.data import image_batch, mixed_shape_batch
 from repro.models import registry as reg
 from repro.nn import conv
 from repro.nn import substrate as sub
+from repro.serving import engine as engine_mod
 from repro.serving import (EdgeDetectService, MicroBatcher, Request,
                            ServingEngine, ServingMetrics, SlotScheduler)
 from tests.test_models_smoke import reduced
@@ -560,3 +561,115 @@ def test_serving_engine_accepts_registry_instance_rejects_custom():
     with pytest.raises(ValueError, match="does not match the registered"):
         ServingEngine(bundle, params, batch_size=1, max_len=16,
                       substrate=Custom("proposed"))
+
+
+# ---------------------------------------------------------------------------
+# Device sampling (sample_tokens) and the engine's per-loop keys
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("temps", [
+    [0.0, 0.0, 0.0, 0.0, 0.0, 0.0],         # every row greedy
+    [0.0, 1.0, -1.0, 0.7, 0.0, 2.0],        # greedy and sampled in one batch
+])
+def test_sample_tokens_greedy_rows_take_the_argmax(temps):
+    rng = np.random.default_rng(0)
+    logits = rng.normal(size=(6, 300)).astype(np.float32)
+    logits[0, [17, 250]] = logits[0].max() + 1.0      # a tie: first wins
+    logits[1] = 0.0                                   # uniform when sampled
+    temps = np.asarray(temps, np.float32)
+    greedy = temps <= 0
+    for k in range(5):
+        got = np.asarray(engine_mod.sample_tokens(
+            jnp.asarray(logits), jnp.asarray(temps), jax.random.PRNGKey(k), 0))
+        assert got.dtype == np.int32 and got.shape == (6,)
+        np.testing.assert_array_equal(got[greedy],
+                                      logits.argmax(-1)[greedy])
+        assert ((0 <= got) & (got < 300)).all()
+    assert int(got[0]) == 17
+    if not greedy[1]:     # a uniform row at T=1 does not sit on one index
+        draws = {int(engine_mod.sample_tokens(
+            jnp.asarray(logits), jnp.asarray(temps),
+            jax.random.PRNGKey(k), 0)[1]) for k in range(20)}
+        assert len(draws) > 1
+
+
+@pytest.mark.parametrize("temp", [0.7, 1.0])
+def test_sample_tokens_draws_softmax_over_temperature(temp):
+    n = 20_000
+    row = np.array([2.0, 1.0, 0.5, 0.0, -0.5, -1.0, -2.0, 3.0], np.float32)
+    got = np.asarray(engine_mod.sample_tokens(
+        jnp.asarray(np.tile(row, (n, 1))), jnp.full((n,), temp),
+        jax.random.PRNGKey(11), 0))
+    p = np.exp(row / temp - (row / temp).max())
+    p /= p.sum()
+    freq = np.bincount(got, minlength=8) / n
+    sigma = np.sqrt(p * (1 - p) / n)
+    assert (np.abs(freq - p) <= 4 * sigma).all(), (freq, p)
+
+
+def test_sample_tokens_draws_afresh_at_every_step():
+    """One loop's key with the step folded in: the same logits give other
+    draws at another step, and the same draws at the same step."""
+    logits, temps = jnp.zeros((8, 1000)), jnp.ones((8,))
+    key = jax.random.PRNGKey(4)
+    draws = [np.asarray(engine_mod.sample_tokens(logits, temps, key, t))
+             for t in (0, 1, 0)]
+    np.testing.assert_array_equal(draws[0], draws[2])
+    assert (draws[0] != draws[1]).any()
+
+
+def _sampled_requests(n, max_tokens=8):
+    return [Request(prompt=[1, 2, 3], max_tokens=max_tokens, temperature=1.0)
+            for _ in range(n)]
+
+
+def test_serving_engine_same_seed_same_tokens():
+    bundle, params = _lm_bundle()
+    runs = []
+    for seed in (5, 5, 6):
+        eng = ServingEngine(bundle, params, batch_size=2, max_len=32,
+                            seed=seed)
+        runs.append([r.output for r in eng.generate(_sampled_requests(2))])
+    assert runs[0] == runs[1]
+    assert runs[0] != runs[2]
+
+
+def test_serving_engine_worker_loops_draw_different_streams():
+    """Two loops fed the same prompts at T=1 draw their own tokens; within
+    a loop, the two slots draw their own too."""
+    bundle, params = _lm_bundle()
+    eng = ServingEngine(bundle, params, batch_size=2, max_len=32, seed=3)
+    reqs = eng.generate(_sampled_requests(4), workers=2)
+    # round-robin: loop 0 serves requests 0 and 2, loop 1 serves 1 and 3
+    assert all(len(r.output) == 8 for r in reqs)
+    assert reqs[0].output != reqs[1].output
+    assert reqs[2].output != reqs[3].output
+    assert reqs[0].output != reqs[2].output
+
+
+def test_serving_engine_step_returns_device_logits_the_driver_can_read():
+    """``_step`` hands back (batch, vocab) float32 logits; numpy stacks
+    them and their column blocks, as the benchmark driver's check does."""
+    bundle, params = _lm_bundle(seed=3)
+    eng = ServingEngine(bundle, params, batch_size=2, max_len=16)
+    state, steps = eng._init_state(), []
+    decode = jax.jit(bundle.decode_step)
+    want_state = bundle.init_decode_state(2, 16)
+    for t, toks in enumerate(([1, 4], [2, 5], [3, 6])):
+        tokens = np.asarray(toks, np.int64)
+        logits, state = eng._step(state, tokens, t)
+        assert jnp.asarray(logits) is logits.array      # no host round trip
+        assert np.asarray(logits).shape == (2, 64)
+        assert np.asarray(logits).dtype == np.float32
+        want, want_state = decode(params, want_state, {
+            "token": jnp.asarray(tokens[:, None], jnp.int32),
+            "cache_len": jnp.asarray(t, jnp.int32)})
+        np.testing.assert_array_equal(np.asarray(logits),
+                                      np.asarray(want[:, 0], np.float32))
+        steps.append(logits)
+    whole = np.stack(steps, axis=1)
+    assert whole.shape == (2, 3, 64)
+    blocks = [np.stack([x[:, lo:lo + 16] for x in steps], axis=1)
+              for lo in range(0, 64, 16)]
+    np.testing.assert_array_equal(np.concatenate(blocks, axis=-1), whole)
