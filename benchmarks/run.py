@@ -2,22 +2,15 @@
 
 Prints a ``name,us_per_call,derived`` CSV at the end (plus human-readable
 tables as it goes). ``python -m benchmarks.run [--only table4]
-[--substrates exact,approx_pallas] [--sharded]`` — the substrate-sweep
-benches (fig9, kernel) default to every substrate registered in
-``repro.nn.substrate``; ``--sharded`` adds the kernel bench's
-``dot_general`` + ``Partitioning`` rows (sweeps sharded contractions over a
-mesh of every visible device — the TPU-native run's sharded sweep).
+[--substrates exact,approx_pallas]`` — the substrate-sweep bench (fig9)
+defaults to every substrate registered in ``repro.nn.substrate``.
 
-Machine-readable artifacts: the ``kernel`` bench writes
-``BENCH_kernels.json``, the ``serve_edge`` bench writes
-``BENCH_serving.json`` (throughput/latency records + the substrate-meter
-energy rollup), and the ``autotune`` bench writes ``BENCH_autotune.json``
-(plan-vs-uniform PDP/PSNR table; ``--plan`` evaluates a saved plan/bundle
-instead of searching), and the ``qat`` bench writes ``BENCH_qat.json``
-(pre/post-QAT quality across wirings × widths + recovered operating
-points) at the repo root, so one ``python -m benchmarks.run`` produces the
-full perf trajectory. A span trace of the serving run is opt-in via
-``benchmarks/edge_serving.py --trace``.
+Machine-readable artifacts: the ``autotune`` bench writes
+``BENCH_autotune.json`` (plan-vs-uniform PDP/PSNR table; ``--plan``
+evaluates a saved plan/bundle instead of searching), and the ``qat`` bench
+writes ``BENCH_qat.json`` (pre/post-QAT quality across wirings × widths +
+recovered operating points) at the repo root. These are accuracy records;
+speed is measured on the chip by ``bench/run.py``.
 """
 from __future__ import annotations
 
@@ -27,10 +20,8 @@ import traceback
 
 from benchmarks import (
     autotune_plan,
-    edge_serving,
     fig9_edge,
     fig10_tradeoff,
-    kernelbench,
     qat_recovery,
     table2_compressors,
     table3_compressor4,
@@ -46,26 +37,21 @@ MODULES = {
     "table5": table5_hardware,
     "fig9": fig9_edge,
     "fig10": fig10_tradeoff,
-    "kernel": kernelbench,
-    "serve_edge": edge_serving,
     "autotune": autotune_plan,
     "qat": qat_recovery,
 }
 
 
 # benches that sweep the ProductSubstrate registry (accept substrates=[...])
-_SUBSTRATE_SWEEPS = ("fig9", "kernel", "serve_edge")
+_SUBSTRATE_SWEEPS = ("fig9",)
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, choices=list(MODULES))
     ap.add_argument("--substrates", default=None,
-                    help="CSV of substrate specs for the sweep benches "
+                    help="CSV of substrate specs for the fig9 sweep "
                          "(default: all registered)")
-    ap.add_argument("--sharded", action="store_true",
-                    help="add the kernel bench's sharded dot_general rows "
-                         "(Partitioning over a mesh of all visible devices)")
     ap.add_argument("--plan", default=None, metavar="PATH",
                     help="substrate-plan JSON or bundle dir for the "
                          "autotune bench (default: greedy search)")
@@ -79,8 +65,6 @@ def main() -> None:
         if args.only and name != args.only:
             continue
         kwargs = {"substrates": substrates} if name in _SUBSTRATE_SWEEPS else {}
-        if name == "kernel":
-            kwargs["sharded"] = args.sharded
         if name == "autotune":
             kwargs["plan"] = args.plan
         try:

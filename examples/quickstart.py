@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import energy, lut, metrics, multiplier as m
-from repro.nn import approx_dot
+from repro.nn import ContractionSpec, QuantPolicy, get_substrate
 
 
 def main(plan_path=None):
@@ -36,10 +36,11 @@ def main(plan_path=None):
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(4, 64)).astype(np.float32))
     w = jnp.asarray(rng.normal(size=(64, 32)).astype(np.float32))
-    y_exact = approx_dot.approx_dot(x, w, mode="exact")
-    y_approx = approx_dot.approx_dot(x, w, mode="approx_bitexact")
+    spec = ContractionSpec(quant=QuantPolicy())
+    y_exact = get_substrate("exact").dot_general(x, w, spec)
+    y_approx = get_substrate("approx_bitexact").dot_general(x, w, spec)
     rel = float(jnp.linalg.norm(y_approx - y_exact) / jnp.linalg.norm(y_exact))
-    print(f"\napprox_dot relative error vs float matmul: {rel:.4f}")
+    print(f"\napproximate matmul relative error vs float: {rel:.4f}")
 
     # 5. the deployment LUT artifact
     table = lut.build_lut("proposed")

@@ -21,7 +21,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="~100M params, 300 steps (slow on CPU)")
-    ap.add_argument("--dot-mode", default="exact",
+    ap.add_argument("--dot-plan", default="exact",
                     choices=["exact", "int8", "approx_stat", "approx_bitexact"])
     ap.add_argument("--ckpt-dir", default="/tmp/repro_example_ckpt")
     args = ap.parse_args()
@@ -36,7 +36,7 @@ def main():
                     remat=False)
         steps, batch, seq = 60, 8, 64
 
-    cfg = reg.get_config("minitron-8b", dot_mode=args.dot_mode, **over)
+    cfg = reg.get_config("minitron-8b", dot_plan=args.dot_plan, **over)
     bundle = reg._BUILDERS[cfg.family](cfg)
 
     loop = TrainLoop(
@@ -50,7 +50,7 @@ def main():
         lambda: bundle.init_params(jax.random.PRNGKey(0)))
     n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
     print(f"training {n_params:,} params from step {start} "
-          f"(dot_mode={cfg.dot_mode}); checkpoints -> {args.ckpt_dir}")
+          f"(dot_plan={cfg.dot_plan}); checkpoints -> {args.ckpt_dir}")
     loop.run(params, opt_state, stream, start,
              on_step=lambda s, l: (s % 10 == 0) and print(
                  f"  step {s:4d}  loss {l:.4f}", flush=True))

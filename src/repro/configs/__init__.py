@@ -1,9 +1,8 @@
-"""Assigned architecture configs (one module per arch) + the paper's own app.
+"""Assigned architecture configs (one module per arch).
 
 Importing this package registers every config with the model registry.
 """
 from repro.configs import (  # noqa: F401
-    edge_detect,
     gemma3_27b,
     internlm2_20b,
     kimi_k2_1t_a32b,

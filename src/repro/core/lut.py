@@ -65,7 +65,7 @@ def build_lut(mult_name: str) -> np.ndarray:
 
     Runs under ``ensure_compile_time_eval`` so the table stays concrete even
     when first requested inside an outer trace (e.g. lowering a model whose
-    dot_mode consults the LUT). Aliases and the implicit ``@8`` width are
+    ``dot_plan`` consults the LUT). Aliases and the implicit ``@8`` width are
     canonicalized before caching, so ``"proposed"``, ``"proposed@8"`` and a
     spec-derived key share one table.
     """

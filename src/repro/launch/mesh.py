@@ -152,23 +152,12 @@ def _fit_spec(spec: P, shape, mesh: Mesh, path_names) -> P:
 
 
 def param_shardings(tree, mesh: Mesh):
-    """NamedSharding tree for params (or optimizer state) by name rules.
-
-    REPRO_NO_FSDP=1 drops the ("pod","data") weight sharding (TP-only,
-    weights resident) — the right trade for decode, where per-step FSDP
-    gathers dominate collectives (hillclimb knob)."""
-    import os as _os
-    no_fsdp = _os.environ.get("REPRO_NO_FSDP")
-
+    """NamedSharding tree for params (or optimizer state) by name rules."""
     def leaf(path, x):
         names = _path_names(path)
         spec = _match_rule(names)
         if spec is None:
             return NamedSharding(mesh, P())
-        if no_fsdp:
-            dims = [None if (isinstance(d, tuple) or d in ("pod", "data"))
-                    else d for d in spec]
-            spec = P(*dims)
         return NamedSharding(mesh, _fit_spec(spec, x.shape, mesh, names))
 
     return jax.tree_util.tree_map_with_path(leaf, tree)
@@ -191,10 +180,7 @@ def batch_shardings(tree, mesh: Mesh):
 def cache_shardings(tree, mesh: Mesh):
     """Decode caches: shard the batch-like dim; stacked caches have a
     leading layer dim. SSM states (B, ...) shard dim 0; KV caches
-    (L, B, S, H, dh) shard dim 1. REPRO_CACHE_SHARD=heads disables the
-    longest-dim (sequence) fallback — hillclimb knob."""
-    import os as _os
-    mode = _os.environ.get("REPRO_CACHE_SHARD", "auto")
+    (L, B, S, H, dh) shard dim 1."""
     present = tuple(n for n in ("pod", "data") if n in mesh.axis_names)
     prod = int(np.prod([mesh.shape[n] for n in present]))
 
@@ -202,21 +188,16 @@ def cache_shardings(tree, mesh: Mesh):
         if not hasattr(x, "shape") or len(x.shape) == 0:
             return NamedSharding(mesh, P())
         spec = [None] * len(x.shape)
-        # "heads" mode: never shard a sequence-like dim (dynamic cache
-        # slices/updates on a seq-sharded cache cost collective-permutes)
-        batch_dims = 1 if mode == "heads" else min(2, len(x.shape))
-        for dim in range(batch_dims):
+        for dim in range(min(2, len(x.shape))):
             if x.shape[dim] % prod == 0:
                 spec[dim] = present
                 break
         else:
             # batch doesn't divide (e.g. long_500k batch=1): shard the
             # longest dim instead (sequence sharding of the KV cache)
-            if mode != "heads":
-                sizes = [(s, i) for i, s in enumerate(x.shape)]
-                s, i = max(sizes)
-                if s % prod == 0:
-                    spec[i] = present
+            s, i = max((s, i) for i, s in enumerate(x.shape))
+            if s % prod == 0:
+                spec[i] = present
         # head dim of KV caches (ndim-2) over "model" when divisible
         if "model" in mesh.axis_names and len(x.shape) >= 4:
             hd = len(x.shape) - 2

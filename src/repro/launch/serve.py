@@ -1,8 +1,7 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
 Runs the batched serving engine with synthetic requests (reduced configs on
-CPU; full-scale serving graphs are exercised by the dry-run's prefill /
-decode lowering).
+CPU).
 
 Telemetry: ``--metrics-out`` dumps the engine's metrics registry
 (Prometheus text for ``.prom``/``.txt`` paths, JSON otherwise) and

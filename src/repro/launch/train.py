@@ -3,7 +3,7 @@
 Wires config → model bundle → optimizer → fault-tolerant TrainLoop over a
 mesh (production 16×16 / 2×16×16, or a debug mesh over local devices).
 Reduced-size overrides make the same path runnable on one CPU for the
-examples and tests; the dry-run covers the full-scale lowering.
+examples and tests.
 """
 from __future__ import annotations
 
@@ -40,14 +40,11 @@ def add_reduced_overrides(ap: argparse.ArgumentParser):
     ap.add_argument("--n-heads", type=int, default=None)
     ap.add_argument("--n-kv-heads", type=int, default=None)
     ap.add_argument("--n-experts", type=int, default=None)
-    ap.add_argument("--dot-mode", default=None,
-                    help="uniform substrate spec, e.g. 'exact', 'int8', or "
-                         "'approx_bitexact:proposed@6' (any registered "
-                         "backend:mult@width)")
     ap.add_argument("--dot-plan", default=None,
-                    help="site-addressed substrate plan: a spec string, "
-                         "inline plan JSON, or path to a plan .json "
-                         "(e.g. an autotuner bundle's plan)")
+                    help="substrate plan: a uniform spec string (e.g. "
+                         "'int8', 'approx_bitexact:proposed@6'), inline "
+                         "plan JSON, or path to a plan .json (e.g. an "
+                         "autotuner bundle's plan)")
 
 
 def overrides_from(args) -> dict:
@@ -55,14 +52,10 @@ def overrides_from(args) -> dict:
             "d_ff": args.d_ff, "vocab": args.vocab, "n_heads": args.n_heads,
             "n_kv_heads": args.n_kv_heads, "n_experts": args.n_experts}
     out = {k: v for k, v in keys.items() if v is not None}
-    # --dot-plan (site-addressed) wins over --dot-mode (uniform shorthand);
-    # both land in cfg.dot_plan so any registered arch trains on an
-    # approximate substrate without a dedicated config
-    if getattr(args, "dot_plan", None):
+    # any registered arch trains on an approximate substrate without a
+    # dedicated config
+    if args.dot_plan:
         out["dot_plan"] = parse_plan_arg(args.dot_plan)
-    elif args.dot_mode:
-        out["dot_plan"] = plan_mod.SubstratePlan.uniform(
-            plan_mod._check_spec(args.dot_mode))
     return out
 
 

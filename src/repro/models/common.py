@@ -10,7 +10,7 @@ lower efficiently at 1T-parameter scale:
   materialize);
 * MoE uses capacity-based sort-free dispatch (bincount ranks + scatter),
   giving the true T·k/E expert FLOP profile instead of dense all-experts;
-* every matmul routes through ``dense()`` which resolves ``cfg.dot_mode``
+* every matmul routes through ``dense()`` which resolves ``cfg.dot_plan``
   through the :mod:`repro.nn.substrate` ProductSubstrate registry — the
   paper's approximate multiplier (and its Pallas TPU kernel,
   ``approx_pallas``) is a first-class execution mode of the whole model zoo.
@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import warnings
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -80,19 +79,13 @@ class ModelConfig:
     dtype: Any = jnp.bfloat16      # weights and caches
     act_dtype: Any = None          # activations from the embedding on
                                    # (None → dtype)
-    dot_mode: str = "exact"        # DEPRECATED single substrate spec
-                                   # "backend[:mult_name]"; kept as the
-                                   # uniform-plan shim — prefer dot_plan
     dot_plan: Any = None           # site-addressed substrate assignment:
                                    # a repro.nn.plan.SubstratePlan (or a
                                    # spec string / plan dict, normalized by
-                                   # substrate_plan()); None → dot_mode
+                                   # substrate_plan()); None → "exact"
     remat: bool = True
     attn_chunk: int = 512
     loss_chunk: int = 512
-    cost_unroll: bool = False   # unroll inner (seq-chunk) scans so XLA
-                                # cost_analysis counts every iteration —
-                                # used by the roofline cost lowerings only
 
     @property
     def dh(self) -> int:
@@ -145,8 +138,8 @@ class ModelConfig:
 # ---------------------------------------------------------------------------
 
 
-#: dense()'s quantization boundary: the historical `dot` policy (per-tensor
-#: dynamic activation scale, per-output-channel weight scales).
+#: dense()'s quantization boundary: the default policy (per-tensor dynamic
+#: activation scale, per-output-channel weight scales).
 _DENSE_QUANT = psub.QuantPolicy()
 
 
@@ -156,25 +149,16 @@ def substrate_plan(cfg: ModelConfig) -> "splan.SubstratePlan":
     An active :func:`repro.nn.plan.plan_override_scope` wins outright — it
     is how a layer above an already-built model function (the train loop
     resuming under a checkpoint's recorded plan) changes the numerics of
-    the whole trace. Otherwise ``cfg.dot_plan`` wins when set (a plan, spec
+    the whole trace. Otherwise ``cfg.dot_plan`` when set (a plan, spec
     string, or plan dict — normalized through
-    :func:`repro.nn.plan.as_plan`); otherwise the legacy ``cfg.dot_mode``
-    spec auto-wraps into a uniform single-rule plan. The legacy path emits
-    a DeprecationWarning for non-default specs — set
-    ``dot_plan=SubstratePlan.uniform(spec)`` (or just ``dot_plan=spec``)
-    instead.
+    :func:`repro.nn.plan.as_plan`); otherwise every site is ``"exact"``.
     """
     override = splan.current_plan_override()
     if override is not None:
         return override
     if cfg.dot_plan is not None:
         return splan.as_plan(cfg.dot_plan)
-    if cfg.dot_mode != "exact":
-        warnings.warn(
-            "cfg.dot_mode is deprecated; set cfg.dot_plan to a "
-            "repro.nn.plan.SubstratePlan (a spec string still means a "
-            "uniform plan)", DeprecationWarning, stacklevel=3)
-    return splan.SubstratePlan.uniform(cfg.dot_mode)
+    return splan.SubstratePlan.uniform("exact")
 
 
 def dense(cfg: ModelConfig, x: Array, w: Array, b: Optional[Array] = None,
@@ -196,8 +180,8 @@ def dense(cfg: ModelConfig, x: Array, w: Array, b: Optional[Array] = None,
 
     The contraction runs through ``dot_general`` with the default
     quantization policy; when a
-    :func:`repro.nn.substrate.partitioning_scope` is active (the launch
-    layer's ``--dot-partition`` mesh path), the contraction lowers through
+    :func:`repro.nn.substrate.partitioning_scope` is active (a sharded
+    caller's mesh path), the contraction lowers through
     shard_map instead of relying on GSPMD to shard the scalar-emulation HLO.
     """
     plan = substrate_plan(cfg)
@@ -302,7 +286,7 @@ def rope(x: Array, positions: Array, theta: float,
 
 def attention_chunked(q: Array, k: Array, v: Array, *, q_offset: Array,
                       causal: bool = True, window: int = 0,
-                      chunk: int = 512, unroll: bool = False) -> Array:
+                      chunk: int = 512) -> Array:
     """Online-softmax attention.
 
     q: (B, Sq, H, dh); k, v: (B, Skv, Hkv, dh); q_offset: scalar — the
@@ -366,7 +350,6 @@ def attention_chunked(q: Array, k: Array, v: Array, *, q_offset: Array,
             body, carry,
             (kc.transpose(1, 0, 2, 3, 4), vc.transpose(1, 0, 2, 3, 4),
              jnp.arange(n_chunks)),
-            unroll=n_chunks if unroll else 1,
         )
     if rem:
         carry = combine(carry, score_block(k[:, n_chunks * chunk:],
@@ -434,7 +417,6 @@ def attn_block(cfg: ModelConfig, p: Params, x: Array, *, positions: Array,
         q, k, v,
         q_offset=(q_offset if q_offset is not None else jnp.array(0, jnp.int32)),
         causal=causal, window=window, chunk=cfg.attn_chunk,
-        unroll=cfg.cost_unroll,
     )
     with splan.site_scope("attn"):
         out = dense(cfg, out.reshape(b, s, h * dh), p["wo"]["w"], site="wo")
@@ -682,8 +664,7 @@ def lm_loss_chunked(cfg: ModelConfig, p: Params, x: Array, labels: Array) -> Arr
         return acc + (logz - gold).sum(), None
 
     total, _ = jax.lax.scan(jax.checkpoint(body), jnp.zeros((), jnp.float32),
-                            jnp.arange(n),
-                            unroll=n if cfg.cost_unroll else 1)
+                            jnp.arange(n))
     rem = s - n * chunk
     if rem:
         logits = head(x[:, n * chunk:])

@@ -247,7 +247,7 @@ def decode_step(cfg: cm.ModelConfig, params: Params, caches, token: Array,
 
 def prefill(cfg: cm.ModelConfig, params: Params, tokens: Array,
             patch_embeds: Optional[Array] = None) -> Array:
-    """Prefill forward: returns last-position logits (caches implicit —
-    the dry-run lowers the compute; a serving engine would also emit KV)."""
+    """Prefill forward: returns last-position logits (caches implicit — a
+    serving engine would also emit KV)."""
     x = forward(cfg, params, tokens, patch_embeds)
     return cm.lm_logits(cfg, params["embed"], x[:, -1:, :])

@@ -2,8 +2,8 @@
 
 Every assigned architecture registers here; ``--arch <id>`` in the launchers
 resolves through this table. Also provides ``input_specs`` —
-ShapeDtypeStruct stand-ins for every model input per (arch × shape), used
-by the multi-pod dry-run (no allocation).
+ShapeDtypeStruct stand-ins for every model input per (arch × shape), for
+lowering a cell without allocating it.
 """
 from __future__ import annotations
 
@@ -161,7 +161,7 @@ def _ensure_loaded():
 
 
 # ---------------------------------------------------------------------------
-# input specs (ShapeDtypeStruct stand-ins, dry-run pattern)
+# input specs (ShapeDtypeStruct stand-ins)
 # ---------------------------------------------------------------------------
 
 

@@ -1,5 +1,5 @@
 """Logical-axis sharding: one model code path for 1-device smoke tests and
-512-device dry-runs.
+sharded meshes.
 
 Model code annotates tensors with *logical* axis names
 (``constrain(x, "batch", "seq", "embed")``); a rules table maps logical names

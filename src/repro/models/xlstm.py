@@ -50,7 +50,7 @@ def init_mlstm(key, cfg: cm.ModelConfig) -> Params:
     }
 
 
-def mlstm_scan(q, k, v, i_gate, f_gate, state, chunk: int, unroll: bool = False):
+def mlstm_scan(q, k, v, i_gate, f_gate, state, chunk: int):
     """Chunked linear-attention recurrence.
 
     q,k,v: (B,S,H,dh); i_gate/f_gate: (B,S,H) in (0,1);
@@ -90,8 +90,7 @@ def mlstm_scan(q, k, v, i_gate, f_gate, state, chunk: int, unroll: bool = False)
             "buhd,buhe->bhde", ku_tail, vv.astype(jnp.float32))
         return st_new, (y_intra + y_inter)
 
-    state, ys = jax.lax.scan(jax.checkpoint(body), state, (qc, kc, vc, ic, fc),
-                             unroll=n if unroll else 1)
+    state, ys = jax.lax.scan(jax.checkpoint(body), state, (qc, kc, vc, ic, fc))
     y = ys.transpose(1, 0, 2, 3, 4).reshape(b, s, h, dh)
     return y, state
 
@@ -109,8 +108,7 @@ def mlstm_block(cfg: cm.ModelConfig, p: Params, x: Array,
     if state is None:
         state = jnp.zeros((b, h, d // h, d // h), jnp.float32)
     y, new_state = mlstm_scan(q, k, v, i_gate, f_gate, state,
-                              chunk=min(cfg.attn_chunk, s),
-                              unroll=cfg.cost_unroll)
+                              chunk=min(cfg.attn_chunk, s))
     y = y.reshape(b, s, d).astype(x.dtype)
     gate = jax.nn.sigmoid(cm.dense(cfg, xn, p["wo_gate"]["w"], site="wo_gate").astype(jnp.float32))
     y = (y.astype(jnp.float32) * gate).astype(x.dtype)

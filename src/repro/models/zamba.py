@@ -65,7 +65,7 @@ def _causal_conv1d(x: Array, w: Array, state=None):
     return out, new_state
 
 
-def mamba_scan(xh, dt, B, C, a, state, chunk: int, unroll: bool = False):
+def mamba_scan(xh, dt, B, C, a, state, chunk: int):
     """SSD chunked recurrence.
 
     xh: (B,S,H,dh); dt: (B,S,H) >0; B,C: (B,S,n); a: (H,) negative;
@@ -103,8 +103,7 @@ def mamba_scan(xh, dt, B, C, a, state, chunk: int, unroll: bool = False):
 
     state, ys = jax.lax.scan(jax.checkpoint(body), state,
                              (xc.astype(jnp.float32), dtc, Bc.astype(jnp.float32),
-                              Cc.astype(jnp.float32)),
-                             unroll=nc if unroll else 1)
+                              Cc.astype(jnp.float32)))
     y = ys.transpose(1, 0, 2, 3, 4).reshape(b, s, h, dh)
     return y, state
 
@@ -126,8 +125,7 @@ def mamba_block(cfg: cm.ModelConfig, p: Params, x: Array, state=None,
         state = jnp.zeros((b, h, dh, n), jnp.float32)
     xh = xin.reshape(b, s, h, dh)
     y, new_state = mamba_scan(xh, dt, Bm, Cm, a, state,
-                              chunk=min(cfg.attn_chunk, s),
-                              unroll=cfg.cost_unroll)
+                              chunk=min(cfg.attn_chunk, s))
     y = y + xh.astype(jnp.float32) * p["d_skip"][None, None, :, None]
     y = (y.reshape(b, s, di) * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
     return (x + cm.dense(cfg, y, p["out_proj"]["w"],

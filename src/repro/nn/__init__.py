@@ -7,7 +7,7 @@ approx_bitexact, approx_lut, approx_stat, approx_pallas).
 (:class:`~repro.nn.plan.SubstratePlan`) — per-layer mixed-substrate
 assignments over the same registry.
 """
-from repro.nn import approx_dot, conv, plan, quant, substrate  # noqa: F401
+from repro.nn import conv, plan, quant, substrate  # noqa: F401
 from repro.nn.plan import SubstratePlan, as_plan  # noqa: F401
 from repro.nn.substrate import (  # noqa: F401
     ContractionSpec,

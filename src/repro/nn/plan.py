@@ -2,9 +2,8 @@
 
 A :class:`SubstratePlan` maps contraction **sites** — stable dotted names
 like ``layer.3.attn.wq`` or ``conv.edge.center`` — to substrate specs
-``backend[:mult_name[@N]]`` (the :mod:`repro.nn.substrate` grammar). It is
-the per-layer generalization of the historical single ``cfg.dot_mode``
-string: a default rule plus glob-style overrides, so one model can run its
+``backend[:mult_name[@N]]`` (the :mod:`repro.nn.substrate` grammar) — a
+default rule plus glob-style overrides, so one model can run its
 attention projections on ``approx_bitexact:proposed@8``, its FFN on a
 cheaper width, and everything unnamed on the default.
 
@@ -157,7 +156,7 @@ class SubstratePlan:
 
     @classmethod
     def uniform(cls, spec: str) -> "SubstratePlan":
-        """A plan equivalent to the legacy single ``dot_mode`` string."""
+        """One spec at every site."""
         return cls(default=str(spec))
 
     def to_dict(self) -> Dict[str, Any]:
@@ -284,7 +283,7 @@ def plan_override_scope(plan: "SubstratePlan | str | dict | None"):
     """Make ``plan`` govern every plan-consulting contraction in the block.
 
     While active, :func:`repro.models.common.substrate_plan` returns this
-    plan instead of the model config's ``dot_plan``/``dot_mode`` — the hook
+    plan instead of the model config's ``dot_plan`` — the hook
     by which a layer *above* an already-built model function (e.g. a
     :class:`repro.train.loop.TrainLoop` resuming from a checkpoint whose
     manifest pins different numerics) can change which substrate each site

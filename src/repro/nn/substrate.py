@@ -15,17 +15,11 @@ one ``dot_general``-style contraction entry point
   through ``shard_map`` — data-parallel M, reduce-scattered K — while
   staying bit-identical to the unsharded path for every bit-exact backend,
 
-plus the raw product model and thin compatibility wrappers
+plus the raw product model and the integer contraction
 
 * ``scalar(a, b)``   — the raw intN×intN→int32 product model,
 * ``dot_int(a, b)``  — 2-D integer-domain (M,K)@(K,N) contraction (exact
                        adder; operands are int8 for widths ≤ 8, int16 wider),
-* ``dot_int8``       — deprecated alias of ``dot_int`` (the name was a lie
-                       at N=16),
-* ``dot(x, w)``      — deprecated wrapper: ``dot_general`` with the default
-                       matmul dims + default ``QuantPolicy``,
-* ``conv2d(imgs,k)`` — batched NHW(C) 'same' convolution via im2col +
-                       ``dot_general``,
 
 and :class:`SubstrateMeta` (bit-exactness, operand width, preferred
 backend, cost hints) so launchers/benchmarks can reason about a substrate
@@ -162,11 +156,7 @@ class SubstrateMeta:
 
 @runtime_checkable
 class ProductSubstrate(Protocol):
-    """Anything with the ``dot_general`` contraction surface + metadata.
-
-    ``dot_int8`` / ``dot`` / ``conv2d`` are thin deprecated wrappers kept
-    for signature stability — every one routes through ``dot_general``.
-    """
+    """Anything with the ``dot_general`` contraction surface + metadata."""
 
     meta: SubstrateMeta
 
@@ -176,12 +166,6 @@ class ProductSubstrate(Protocol):
                     spec: "Optional[ContractionSpec]" = None) -> Array: ...
 
     def dot_int(self, a: Array, b: Array) -> Array: ...
-
-    def dot_int8(self, a8: Array, b8: Array) -> Array: ...  # deprecated alias
-
-    def dot(self, x: Array, w: Array) -> Array: ...         # deprecated wrapper
-
-    def conv2d(self, imgs: Array, kernel: Array) -> Array: ...
 
 
 # ---------------------------------------------------------------------------
@@ -195,7 +179,7 @@ DimensionNumbers = Tuple[Tuple[Tuple[int, ...], Tuple[int, ...]],
                          Tuple[Tuple[int, ...], Tuple[int, ...]]]
 
 #: Plain matmul dims: contract the last lhs axis with the first rhs axis —
-#: valid for any lhs rank (the historical ``dot(x, w)`` shape contract).
+#: valid for any lhs rank (``x @ w`` for x (..., K), w (K, N)).
 MATMUL_DIMS: DimensionNumbers = (((-1,), (0,)), ((), ()))
 
 
@@ -203,18 +187,18 @@ MATMUL_DIMS: DimensionNumbers = (((-1,), (0,)), ((), ()))
 class QuantPolicy:
     """Float→intN quantization boundary policy for ``dot_general``.
 
-    Extracted from the historical ``dot`` so callers can vary (or pin) the
-    policy per call site instead of inheriting one hard-coded choice.
+    Callers vary (or pin) the policy per call site; the defaults are
+    ``dense()``'s.
 
     bits:     operand width to quantize to (None → the substrate's
               ``meta.width``; must not exceed it — wider codes would wrap in
               the narrower multiplier).
     x_mode:   activation scale granularity — ``"per_tensor"`` (one dynamic
-              scalar scale, the historical default) or ``"per_channel"``
+              scalar scale, the default) or ``"per_channel"``
               (one scale per output row, i.e. per flattened lhs free
               element).
     w_mode:   weight scale granularity — ``"per_channel"`` (one scale per
-              flattened rhs free element, the historical default) or
+              flattened rhs free element, the default) or
               ``"per_tensor"``.
     x_scale / w_scale:
               pinned scales. When set, the dynamic absmax computation is
@@ -327,7 +311,7 @@ class ContractionSpec:
     def matmul(quant: Optional[QuantPolicy] = None,
                partitioning: Optional[Partitioning] = None,
                site: Optional[str] = None) -> "ContractionSpec":
-        """Plain ``(…, K) @ (K, N)`` spec (the historical ``dot`` shape)."""
+        """Plain ``(…, K) @ (K, N)`` spec."""
         return ContractionSpec(MATMUL_DIMS, quant, partitioning, site)
 
 
@@ -347,9 +331,9 @@ def current_partitioning() -> Optional[Partitioning]:
 def partitioning_scope(p: Optional[Partitioning]):
     """Install an ambient Partitioning for the duration of the block.
 
-    Used by the launch layer (``repro.launch.dryrun --dot-partition``) to
-    lower every model ``dense`` contraction through shard_map without
-    threading a spec through the whole model zoo. ``None`` is a no-op scope.
+    Lets a sharded caller lower every model ``dense`` contraction through
+    shard_map without threading a spec through the whole model zoo.
+    ``None`` is a no-op scope.
     """
     prev = getattr(_PART_STATE, "value", None)
     _PART_STATE.value = p
@@ -630,7 +614,7 @@ def _sharded_dot_float(a: Array, b: Array, part: Partitioning) -> Array:
 
 
 class _SubstrateBase:
-    """Shared ``dot_general`` plumbing + deprecated wrappers."""
+    """Shared ``dot_general`` plumbing."""
 
     meta: SubstrateMeta
     #: the scalar-product model's f(0,0) — the k-padding correction unit.
@@ -735,39 +719,8 @@ class _SubstrateBase:
             return self.dot_int(a, b)
         return _sharded_dot_int(self, a, b, partitioning)
 
-    # -- deprecated wrappers (kept signatures; all route via dot_general) ----
-
-    def dot_int8(self, a8: Array, b8: Array) -> Array:
-        """Deprecated alias of :meth:`dot_int` — the name was a lie at
-        N=16, where operands are int16."""
-        return self.dot_int(a8, b8)
-
-    def dot(self, x: Array, w: Array) -> Array:
-        """``x @ w`` with this substrate as the scalar-product unit.
-
-        Deprecated wrapper: ``dot_general`` with the plain matmul dims and
-        the default :class:`QuantPolicy` (per-tensor dynamic activation
-        scale, per-output-channel weight scales, substrate width).
-        x: (..., K) activations (any float dtype); w: (K, N) weights.
-        Returns x's dtype.
-        """
-        return self.dot_general(x, w, _DEFAULT_FLOAT_SPEC)
-
-    # -- convolution ---------------------------------------------------------
-
-    def conv2d(self, imgs: Array, kernel: Array) -> Array:
-        """Batched 'same' integer conv (im2col + ``dot_general``); see
-        nn.conv. Deprecated-stable wrapper around ``conv.conv2d_batched``."""
-        from repro.nn import conv  # late import: conv consumes substrates
-
-        return conv.conv2d_batched(imgs, kernel, self)
-
     def __repr__(self) -> str:  # pragma: no cover - debugging aid
         return f"<{type(self).__name__} {self.meta.spec}>"
-
-
-#: the historical ``dot`` behavior as a spec: plain matmul, default policy.
-_DEFAULT_FLOAT_SPEC = ContractionSpec(quant=QuantPolicy())
 
 
 # ---------------------------------------------------------------------------
@@ -825,7 +778,7 @@ class ExactSubstrate(_SubstrateBase):
         x = jnp.asarray(x)
         if spec.quant is not None:
             # the quantization boundary is a no-op here by definition:
-            # contract in the compute dtype (the historical `dot`)
+            # contract in the compute dtype
             w = jnp.asarray(w, x.dtype)
             plan = _plan_contraction(x.shape, w.shape, spec.dimension_numbers)
             self._meter_hook(plan, None, None, site=spec.site)  # no probe
@@ -919,8 +872,8 @@ class StatSubstrate(_SubstrateBase):
 
     E[e(a,b)] ≈ r[a] + c[b] − µ, where e is the multiplier's error LUT and
     r/c its row/column means. Adds two gathers + two rank-1 terms, lowers to
-    MXU-friendly HLO, and is the deployment-scale stand-in used by the
-    multi-pod dry-runs (the Pallas kernel replaces it on real hardware).
+    MXU-friendly HLO: a deployment-scale stand-in for the multiplier (the
+    Pallas kernel is the bit-exact path on real hardware).
     Beyond-paper contribution. The correction is defined at contraction level
     (``scalar_faithful=False``): ``dot_int`` rounds the summed correction
     once per output element, while ``scalar`` rounds per product. Widths ≤ 8

@@ -7,7 +7,7 @@ a counter. A :class:`MetricsRegistry` owns named *families*; a family plus
 a label set is one time series. Two export surfaces:
 
 * :meth:`MetricsRegistry.to_json` — a plain dict (machine-readable dumps,
-  ``BENCH_serving.json`` sections, CI artifact checks);
+  CI artifact checks);
 * :meth:`MetricsRegistry.to_prometheus` — Prometheus text exposition
   format (``# HELP`` / ``# TYPE`` headers, ``name{label="v"} value``
   samples, ``_bucket``/``_sum``/``_count`` for histograms).

@@ -1,8 +1,8 @@
 """AdamW with decoupled weight decay.
 
 State layout mirrors the param tree (each leaf becomes {"m": ..., "v": ...})
-so the dry-run's name-based sharding rules apply to optimizer state
-transparently.
+so the name-based sharding rules of ``launch.mesh`` apply to optimizer
+state transparently.
 """
 from __future__ import annotations
 

@@ -40,7 +40,6 @@ bit-identical to the single-worker path on every substrate.
 from __future__ import annotations
 
 import threading
-import time
 from typing import Iterable, List, Optional, Sequence, Tuple
 
 import jax
@@ -77,16 +76,6 @@ class EdgeDetectService:
     n_workers:          worker threads draining the bucketed queue; >1
                         overlaps host-side micro-batching with device
                         compute (results stay bit-identical).
-    device_latency_s:   emulated extra device latency: a ``pure_callback``
-                        sleep stage appended *inside* the compiled call, so
-                        the batch occupies the (emulated) device for this
-                        long after the real contraction — the full async
-                        dispatch/finalize path is exercised while values
-                        pass through unchanged. Lets a host-only runner
-                        measure worker/overlap scaling as if the device were
-                        this slow (benchmarks) and widens race windows
-                        (stress tests). ``0`` (production default) adds no
-                        stage.
     partitioning:       optional :class:`repro.nn.substrate.Partitioning` —
                         the served contraction lowers through shard_map
                         (data-parallel M / reduce-scattered K). Bit-identity
@@ -97,15 +86,12 @@ class EdgeDetectService:
     def __init__(self, substrate: "str | sub.ProductSubstrate" = "approx_bitexact",
                  *, max_batch_size: int = 8, max_wait_s: float = 2e-3,
                  bucket_granularity: int = 16, pad_batches: bool = True,
-                 n_workers: int = 1, device_latency_s: float = 0.0,
+                 n_workers: int = 1,
                  partitioning: Optional[sub.Partitioning] = None,
                  metrics: Optional[ServingMetrics] = None, start: bool = True):
         if bucket_granularity < 1:
             raise ValueError(
                 f"bucket_granularity must be >= 1, got {bucket_granularity}")
-        if device_latency_s < 0:
-            raise ValueError(
-                f"device_latency_s must be >= 0, got {device_latency_s}")
         from repro.nn import plan as plan_mod
         if isinstance(substrate, (plan_mod.SubstratePlan, dict)):
             self.plan = plan_mod.as_plan(substrate)
@@ -117,7 +103,6 @@ class EdgeDetectService:
             self.spec = self.substrate.meta.spec
         self.bucket_granularity = bucket_granularity
         self.pad_batches = pad_batches
-        self.device_latency_s = device_latency_s
         self.partitioning = partitioning
         # the conv kernel every batch runs, known before the first trace
         # (plans contract their tap groups through im2col)
@@ -151,26 +136,12 @@ class EdgeDetectService:
     # -- request path --------------------------------------------------------
 
     def _compute(self, batch):
-        """Traced body of the compiled call: the edge-detect contraction,
-        plus (when ``device_latency_s > 0``) an identity ``pure_callback``
-        stage that holds the result on the emulated device for that long.
-        The callback returns its input untouched, so emulation never
-        perturbs served values — only their timing."""
+        """Traced body of the compiled call: the edge-detect contraction."""
         if self.plan is not None:
-            out = conv.edge_detect_planned(
+            return conv.edge_detect_planned(
                 batch, self.plan, partitioning=self.partitioning)
-        else:
-            out = conv.edge_detect_batched(
-                batch, self.substrate, partitioning=self.partitioning)
-        if self.device_latency_s > 0:
-            out = jax.pure_callback(
-                self._emulate_device,
-                jax.ShapeDtypeStruct(out.shape, out.dtype), out)
-        return out
-
-    def _emulate_device(self, out):
-        time.sleep(self.device_latency_s)
-        return out
+        return conv.edge_detect_batched(
+            batch, self.substrate, partitioning=self.partitioning)
 
     def _bucket(self, img: np.ndarray) -> Tuple[int, int]:
         h, w = img.shape

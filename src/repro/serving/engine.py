@@ -35,10 +35,6 @@ order — a pre-existing property of the shared-``cache_len`` engine that
 holds even at ``workers=1`` (reordering requests changes refilled-slot
 outputs the same way).
 
-For the large-scale path, the *dry-run* lowers the dedicated ``prefill``
-graph (chunked attention, full-sequence); this engine is the functional
-small-scale server used by the examples and tests.
-
 The engine accepts a ``substrate`` override — a ``repro.nn.substrate`` spec
 or a per-site :class:`repro.nn.plan.SubstratePlan` — so int8 / approximate /
 mixed-substrate serving experiments run against the same bundle + params
@@ -135,14 +131,10 @@ class ServingEngine:
             from repro.nn import plan as plan_mod
             from repro.nn import substrate as psub
 
-            spec = None
-            if isinstance(substrate, (plan_mod.SubstratePlan, dict)):
+            if isinstance(substrate, (plan_mod.SubstratePlan, dict, str)):
                 plan = plan_mod.as_plan(substrate)
-            elif isinstance(substrate, str):
-                spec = substrate
-                plan = plan_mod.SubstratePlan.uniform(substrate)
             else:
-                # the model path resolves by spec string (cfg.dot_mode), so a
+                # the model path resolves every site by spec string, so a
                 # substrate instance must be equivalent to what the registry
                 # yields for its spec — a custom subclass would be silently
                 # swapped out for the stock backend here
@@ -155,13 +147,8 @@ class ServingEngine:
                         f"registered backend for {spec!r}; pass a spec string "
                         "or register the backend first")
                 plan = plan_mod.SubstratePlan.uniform(spec)
-            # uniform overrides mirror the spec into cfg.dot_mode too, so
-            # introspection (and pre-plan callers) keep seeing the spec
-            over = {"dot_plan": plan}
-            if spec is not None:
-                over["dot_mode"] = spec
             bundle = reg.build_bundle(
-                dataclasses.replace(bundle.cfg, **over))
+                dataclasses.replace(bundle.cfg, dot_plan=plan))
         self.bundle = bundle
         self.cfg = bundle.cfg
         self.params = params

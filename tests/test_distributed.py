@@ -66,10 +66,11 @@ def test_compressed_dp_step_matches_uncompressed():
 
 
 def test_dryrun_cell_on_debug_mesh():
-    """lower+compile a reduced arch on a 4x2 mesh; roofline terms emitted."""
+    """A reduced arch's sharded train step lowers and compiles on a 4x2
+    mesh: XLA counts its flops, and the step communicates."""
     out = run_py("""
         import jax, jax.numpy as jnp, json
-        from repro.launch import mesh as mesh_lib, roofline
+        from repro.launch import mesh as mesh_lib
         from repro.models import registry as reg
         from repro.optim import adamw
 
@@ -96,16 +97,16 @@ def test_dryrun_cell_on_debug_mesh():
             compiled = lowered.compile()
             cost = compiled.cost_analysis() or {}
             hlo = compiled.as_text()
-        rf = roofline.derive(cost, hlo, 8, roofline.model_flops_for(
-            cfg, reg.SHAPES["train_4k"]))
-        stats = roofline.parse_collectives(hlo)
-        print(json.dumps({"flops": rf.flops_per_device,
-                          "coll": stats.total_bytes,
-                          "bottleneck": rf.bottleneck}))
-        assert rf.flops_per_device > 0
-        assert stats.total_bytes > 0  # sharded training must communicate
+        if isinstance(cost, (list, tuple)):   # one dict per program
+            cost = cost[0] if cost else {}
+        flops = float(cost.get("flops", 0.0))
+        colls = [op for op in ("all-reduce", "reduce-scatter", "all-gather")
+                 if op in hlo]
+        print(json.dumps({"flops": flops, "collectives": colls}))
+        assert flops > 0
+        assert colls  # sharded training must communicate
     """)
-    assert "bottleneck" in out
+    assert "collectives" in out
 
 
 def test_elastic_checkpoint_restore_across_meshes(tmp_path):
@@ -195,8 +196,8 @@ def test_sharded_edge_detect_matches_unsharded():
 
 
 def test_dryrun_partitioned_approx_substrate_lowers():
-    """--dot-partition mesh path: an approx substrate (approx_stat) lowers
-    and compiles on a debug mesh with every dense() through shard_map."""
+    """Partitioned mesh path: an approx substrate (approx_stat) lowers and
+    compiles on a debug mesh with every dense() through shard_map."""
     out = run_py("""
         import jax, jax.numpy as jnp
         from repro.launch import mesh as mesh_lib
@@ -206,7 +207,7 @@ def test_dryrun_partitioned_approx_substrate_lowers():
         cfg = reg.get_config("minitron-8b", n_layers=2, d_model=128, d_ff=256,
                              vocab=512, n_heads=4, n_kv_heads=2,
                              attn_chunk=64, loss_chunk=64, remat=False,
-                             dot_mode="approx_stat")
+                             dot_plan="approx_stat")
         bundle = reg._BUILDERS[cfg.family](cfg)
         mesh = mesh_lib.make_mesh((4, 2), ("data", "model"))
         part = mesh_lib.contraction_partitioning(mesh)

@@ -25,6 +25,8 @@ from repro.nn import substrate as sub
 from repro.nn.substrate import ContractionSpec, Partitioning, QuantPolicy
 
 RNG = np.random.default_rng(7)
+#: dense()'s contraction: plain matmul dims, default quantization policy
+FLOAT = ContractionSpec(quant=QuantPolicy())
 
 ALL_SPECS = ("exact", "int8", "approx_bitexact", "approx_lut",
              "approx_stat", "approx_pallas")
@@ -124,12 +126,15 @@ def test_dimension_number_validation():
 
 @pytest.mark.parametrize("spec", ALL_SPECS)
 def test_float_path_bit_identical_to_dot_wrapper(spec):
+    """The default matmul dims (negative axes) and the same contraction
+    spelled with positive axes give bit-identical float-path results."""
     s = sub.get_substrate(spec)
     x = jnp.asarray(RNG.normal(size=(3, 5, 24)).astype(np.float32))
     w = jnp.asarray(RNG.normal(size=(24, 6)).astype(np.float32))
-    ref = np.asarray(s.dot(x, w))
+    ref = np.asarray(s.dot_general(x, w, FLOAT))
     got = np.asarray(s.dot_general(
-        x, w, ContractionSpec.matmul(quant=QuantPolicy())))
+        x, w, ContractionSpec((((2,), (0,)), ((), ())),
+                              quant=QuantPolicy())))
     np.testing.assert_array_equal(got, ref, err_msg=spec)
 
 
@@ -162,7 +167,7 @@ def test_pinned_scales_reproduce_dynamic():
     qm = 127.0
     x_scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-8) / qm
     w_scale = jnp.maximum(jnp.max(jnp.abs(w), axis=0), 1e-8) / qm  # (N,)
-    dyn = np.asarray(s.dot(x, w))
+    dyn = np.asarray(s.dot_general(x, w, FLOAT))
     pinned = np.asarray(s.dot_general(x, w, ContractionSpec.matmul(
         quant=QuantPolicy(x_scale=x_scale, w_scale=w_scale))))
     np.testing.assert_array_equal(pinned, dyn)
@@ -202,7 +207,7 @@ def test_zero_activations_give_zero_output(spec):
     s = sub.get_substrate(spec)
     x = jnp.zeros((4, 16), jnp.float32)
     w = jnp.asarray(RNG.normal(size=(16, 8)).astype(np.float32))
-    out = np.asarray(s.dot(x, w))
+    out = np.asarray(s.dot_general(x, w, FLOAT))
     assert np.isfinite(out).all(), spec
     assert (np.abs(out) < 1e-6).all(), (spec, np.abs(out).max())
     if s.meta.name in ("exact", "int8"):
@@ -353,9 +358,10 @@ def test_sharded_quantized_float_path_8_devices():
         part = Partitioning(mesh, m_axis="data", k_axis="model")
         x = jnp.asarray(rng.normal(size=(6, 40)).astype(np.float32))
         w = jnp.asarray(rng.normal(size=(40, 8)).astype(np.float32))
+        plain = ContractionSpec(quant=QuantPolicy())
         for spec in ("int8", "approx_bitexact", "approx_lut"):
             s = sub.get_substrate(spec)
-            ref = np.asarray(s.dot(x, w))
+            ref = np.asarray(s.dot_general(x, w, plain))
             got = np.asarray(s.dot_general(x, w, ContractionSpec.matmul(
                 quant=QuantPolicy(), partitioning=part)))
             np.testing.assert_array_equal(got, ref, err_msg=spec)
@@ -363,7 +369,7 @@ def test_sharded_quantized_float_path_8_devices():
         e = sub.get_substrate("exact")
         got = np.asarray(e.dot_general(x, w, ContractionSpec.matmul(
             quant=QuantPolicy(), partitioning=part)))
-        np.testing.assert_allclose(got, np.asarray(e.dot(x, w)),
+        np.testing.assert_allclose(got, np.asarray(e.dot_general(x, w, plain)),
                                    rtol=1e-5, atol=1e-5)
         print("sharded float ok")
     """)

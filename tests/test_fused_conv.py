@@ -107,7 +107,7 @@ def test_kslab_closed_form_exhaustive_n4(name):
     (K=1 forces pad correction)."""
     a, b = _pair_grid(4)
     want = np.asarray(
-        sub.get_substrate(f"approx_bitexact:{name}@4").dot_int8(a, b))
+        sub.get_substrate(f"approx_bitexact:{name}@4").dot_int(a, b))
     for kc in (8, 1):
         got = np.asarray(closed_form_matmul(a, b, f"{name}@4", k_chunk=kc))
         np.testing.assert_array_equal(got, want, err_msg=f"{name} kc={kc}")

@@ -126,9 +126,9 @@ def test_kpad_correction_is_per_wiring_regression():
     m, k, n = 4, 3, 2                    # k=3 pads to the min block of 8
     a = RNG.integers(-128, 128, (m, k)).astype(np.int8)
     b = RNG.integers(-128, 128, (k, n)).astype(np.int8)
-    got = np.asarray(sub.get_substrate(f"approx_pallas:{key}").dot_int8(a, b))
+    got = np.asarray(sub.get_substrate(f"approx_pallas:{key}").dot_int(a, b))
     want = np.asarray(
-        sub.get_substrate(f"approx_bitexact:{key}").dot_int8(a, b))
+        sub.get_substrate(f"approx_bitexact:{key}").dot_int(a, b))
     np.testing.assert_array_equal(got, want)
 
 
@@ -218,9 +218,9 @@ def test_pallas_substrate_exhaustive_n4_matches_bitexact(name):
     grid (as a K=1 contraction, so the pad correction fires too)."""
     a, b = _pair_grid(4)
     got = np.asarray(
-        sub.get_substrate(f"approx_pallas:{name}@4").dot_int8(a, b))
+        sub.get_substrate(f"approx_pallas:{name}@4").dot_int(a, b))
     want = np.asarray(
-        sub.get_substrate(f"approx_bitexact:{name}@4").dot_int8(a, b))
+        sub.get_substrate(f"approx_bitexact:{name}@4").dot_int(a, b))
     np.testing.assert_array_equal(got, want, err_msg=name)
 
 
@@ -235,19 +235,19 @@ def test_pallas_substrate_sampled_matches_bitexact(spec_suffix):
         a = RNG.integers(-128, 128, (m, k)).astype(np.int8)
         b = RNG.integers(-128, 128, (k, n)).astype(np.int8)
         np.testing.assert_array_equal(
-            np.asarray(ps.dot_int8(a, b)), np.asarray(bx.dot_int8(a, b)),
+            np.asarray(ps.dot_int(a, b)), np.asarray(bx.dot_int(a, b)),
             err_msg=f"{spec_suffix} {(m, k, n)}")
 
 
 def test_pallas_substrate_scalar_faithful_lut_path():
-    """dot_int8 == Σ_k scalar(a_k, b_k) on the LUT path too."""
+    """dot_int == Σ_k scalar(a_k, b_k) on the LUT path too."""
     s = sub.get_substrate("approx_pallas:design_strollo2020@4")
     a = RNG.integers(-8, 8, (4, 11)).astype(np.int8)
     b = RNG.integers(-8, 8, (11, 3)).astype(np.int8)
     oracle = np.asarray(s.scalar(jnp.asarray(a[:, :, None], jnp.int32),
                                  jnp.asarray(b[None, :, :], jnp.int32))
                         ).sum(axis=1)
-    np.testing.assert_array_equal(np.asarray(s.dot_int8(a, b)), oracle)
+    np.testing.assert_array_equal(np.asarray(s.dot_int(a, b)), oracle)
 
 
 # ---------------------------------------------------------------------------

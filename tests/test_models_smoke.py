@@ -51,7 +51,7 @@ def tiny_batch(cfg: cm.ModelConfig, b=2, s=32):
     return batch
 
 
-ARCHS = [a for a in reg.list_archs() if a != "edge-detect"]
+ARCHS = reg.list_archs()
 
 
 @pytest.mark.parametrize("arch", ARCHS)

@@ -1,14 +1,15 @@
-"""NN layer: quantization, approx_dot execution modes, edge-detection conv."""
+"""NN layer: quantization, substrate execution modes, edge-detection conv."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import lut as lut_lib
-from repro.nn import approx_dot as ad
 from repro.nn import conv, quant
+from repro.nn import substrate as sub
 
 RNG = np.random.default_rng(7)
+MATMUL = sub.ContractionSpec(quant=sub.QuantPolicy())  # dense()'s contraction
 
 
 def test_quantize_roundtrip_error_bounded():
@@ -34,8 +35,8 @@ def test_quantized_values_in_range():
 def test_bitexact_equals_lut_mode():
     a8 = RNG.integers(-128, 128, (24, 40)).astype(np.int8)
     b8 = RNG.integers(-128, 128, (40, 8)).astype(np.int8)
-    bx = np.asarray(ad.approx_matmul_int8(a8, b8, mode="approx_bitexact"))
-    lt = np.asarray(ad.approx_matmul_int8(a8, b8, mode="approx_lut"))
+    bx = np.asarray(sub.get_substrate("approx_bitexact").dot_int(a8, b8))
+    lt = np.asarray(sub.get_substrate("approx_lut").dot_int(a8, b8))
     np.testing.assert_array_equal(bx, lt)
 
 
@@ -45,14 +46,14 @@ def test_bitexact_matches_dense_oracle():
     table = lut_lib.build_lut("proposed").astype(np.int64)
     oracle = table[a8.astype(np.int64)[:, :, None] + 128,
                    b8.astype(np.int64)[None, :, :] + 128].sum(axis=1)
-    got = np.asarray(ad.approx_matmul_int8(a8, b8, mode="approx_bitexact"))
+    got = np.asarray(sub.get_substrate("approx_bitexact").dot_int(a8, b8))
     np.testing.assert_array_equal(got, oracle)
 
 
 def test_int8_mode_is_exact_integer_matmul():
     a8 = RNG.integers(-128, 128, (12, 33)).astype(np.int8)
     b8 = RNG.integers(-128, 128, (33, 7)).astype(np.int8)
-    got = np.asarray(ad.approx_matmul_int8(a8, b8, mode="int8"))
+    got = np.asarray(sub.get_substrate("int8").dot_int(a8, b8))
     np.testing.assert_array_equal(got, a8.astype(np.int64) @ b8.astype(np.int64))
 
 
@@ -61,19 +62,19 @@ def test_stat_mode_reduces_error_vs_uncorrected():
     bit-exact approximate contraction (it models the multiplier's bias)."""
     a8 = RNG.integers(-128, 128, (32, 256)).astype(np.int8)
     b8 = RNG.integers(-128, 128, (256, 16)).astype(np.int8)
-    bitexact = np.asarray(ad.approx_matmul_int8(a8, b8, mode="approx_bitexact"), np.int64)
-    int8 = np.asarray(ad.approx_matmul_int8(a8, b8, mode="int8"), np.int64)
-    stat = np.asarray(ad.approx_matmul_int8(a8, b8, mode="approx_stat"), np.int64)
+    bitexact = np.asarray(sub.get_substrate("approx_bitexact").dot_int(a8, b8), np.int64)
+    int8 = np.asarray(sub.get_substrate("int8").dot_int(a8, b8), np.int64)
+    stat = np.asarray(sub.get_substrate("approx_stat").dot_int(a8, b8), np.int64)
     err_raw = np.abs(bitexact - int8).mean()
     err_stat = np.abs(bitexact - stat).mean()
     assert err_stat < err_raw * 0.8, (err_stat, err_raw)
 
 
 @pytest.mark.parametrize("mode", ["exact", "int8", "approx_bitexact", "approx_lut", "approx_stat"])
-def test_approx_dot_modes_close_to_float(mode):
+def test_substrate_matmul_modes_close_to_float(mode):
     x = jnp.asarray(RNG.normal(size=(4, 6, 48)).astype(np.float32))
     w = jnp.asarray(RNG.normal(size=(48, 24)).astype(np.float32))
-    out = ad.approx_dot(x, w, mode=mode)
+    out = sub.get_substrate(mode).dot_general(x, w, MATMUL)
     ref = jnp.dot(x, w)
     assert out.shape == ref.shape
     rel = float(jnp.linalg.norm(out - ref) / jnp.linalg.norm(ref))
@@ -85,7 +86,7 @@ def test_approx_dot_modes_close_to_float(mode):
 def test_approx_dot_k_not_multiple_of_chunk():
     x = jnp.asarray(RNG.normal(size=(3, 19)).astype(np.float32))
     w = jnp.asarray(RNG.normal(size=(19, 5)).astype(np.float32))
-    out = ad.approx_dot(x, w, mode="approx_bitexact")
+    out = sub.get_substrate("approx_bitexact").dot_general(x, w, MATMUL)
     assert out.shape == (3, 5) and bool(jnp.all(jnp.isfinite(out)))
 
 

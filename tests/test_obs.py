@@ -354,7 +354,7 @@ class TestMeterRecording:
         x = np.linspace(-1, 1, 32, dtype=np.float32).reshape(4, 8)
         w = np.linspace(-1, 1, 16, dtype=np.float32).reshape(8, 2)
         with telemetry_scope(meter):
-            s.dot(x, w)
+            s.dot_general(x, w, sub.ContractionSpec(quant=sub.QuantPolicy()))
             _flush_callbacks()
         assert meter.summary()["exact:exact"]["contractions"] == 1
         assert meter.probe_moments() == {}  # exact backends are never probed

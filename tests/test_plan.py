@@ -1,9 +1,10 @@
-"""Substrate plans: site resolution, scan dispatch, legacy parity, bundles.
+"""Substrate plans: site resolution, scan dispatch, spec-form parity, bundles.
 
 Bit-identity semantics tested here follow the trace structure:
 
-* a uniform plan and the legacy ``dot_mode`` string build the *same* traced
-  graph, so their outputs are compared bit-for-bit;
+* a uniform plan given as a spec string, a plan, or the plan's dict form
+  builds the *same* traced graph, so their outputs are compared
+  bit-for-bit;
 * the scanned dispatch path vs an unrolled python-loop oracle are
   *different* traces of the same float math — XLA reassociates the
   quantize/rescale arithmetic differently under ``lax.scan`` (measured
@@ -13,7 +14,6 @@ Bit-identity semantics tested here follow the trace structure:
   (tap-group) vs whole-kernel edge maps compare bit-for-bit.
 """
 import dataclasses
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -160,7 +160,7 @@ def test_dispatch_switch_groups_when_repeats_differ():
 
 
 # ---------------------------------------------------------------------------
-# model integration: legacy parity, deprecation shim, scan dispatch
+# model integration: spec-form parity, scan dispatch
 # ---------------------------------------------------------------------------
 
 
@@ -179,32 +179,13 @@ def _prefill_logits(cfg):
 
 
 @pytest.mark.parametrize("spec", ["exact", "approx_bitexact", "approx_lut"])
-def test_uniform_plan_bit_identical_to_legacy_dot_mode(spec):
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        legacy = _prefill_logits(_tiny_cfg(dot_mode=spec))
-    planned = _prefill_logits(
-        _tiny_cfg(dot_plan=splan.SubstratePlan.uniform(spec)))
-    np.testing.assert_array_equal(legacy, planned)
-
-
-def test_dot_mode_deprecation_warning_and_shim():
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        plan = cm.substrate_plan(_tiny_cfg(dot_mode="int8"))
-    assert plan == splan.SubstratePlan.uniform("int8")
-    assert any(issubclass(x.category, DeprecationWarning) for x in w)
-    # exact (the default) and explicit dot_plan stay silent
-    for cfg in (_tiny_cfg(), _tiny_cfg(dot_mode="int8", dot_plan="int8")):
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            cm.substrate_plan(cfg)
-        assert not w
-
-
-def test_dot_plan_wins_over_dot_mode():
-    plan = cm.substrate_plan(_tiny_cfg(dot_mode="int8", dot_plan="approx_lut"))
-    assert plan.default == "approx_lut"
+def test_uniform_plan_bit_identical_across_spec_forms(spec):
+    plan = splan.SubstratePlan.uniform(spec)
+    by_string = _prefill_logits(_tiny_cfg(dot_plan=spec))
+    np.testing.assert_array_equal(
+        by_string, _prefill_logits(_tiny_cfg(dot_plan=plan)))
+    np.testing.assert_array_equal(
+        by_string, _prefill_logits(_tiny_cfg(dot_plan=plan.to_dict())))
 
 
 def test_mixed_plan_under_scan_matches_python_loop_oracle():

@@ -12,6 +12,7 @@ from repro.data import image_batch, mixed_shape_batch
 from repro.models import registry as reg
 from repro.nn import conv
 from repro.nn import substrate as sub
+from repro.nn.plan import SubstratePlan
 from repro.serving import engine as engine_mod
 from repro.serving import (EdgeDetectService, MicroBatcher, Request,
                            ServingEngine, ServingMetrics, SlotScheduler)
@@ -435,7 +436,6 @@ def test_edge_service_reports_its_conv_path(spec, path):
 
 def test_edge_service_plan_and_partitioning_take_im2col():
     from repro.launch.mesh import make_mesh
-    from repro.nn.plan import SubstratePlan
 
     part = sub.Partitioning(make_mesh((1,), ("data",)), m_axis="data")
     plan = SubstratePlan(default="approx_pallas:proposed@8")
@@ -534,11 +534,11 @@ def test_serving_greedy_matches_decode_loop():
 
 def test_serving_engine_substrate_override():
     bundle = reg.build_bundle(_tiny_cfg())
-    assert bundle.cfg.dot_mode == "exact"
+    assert bundle.plan == SubstratePlan.uniform("exact")
     params = bundle.init_params(jax.random.PRNGKey(0))
     eng = ServingEngine(bundle, params, batch_size=1, max_len=32,
                         substrate="int8")
-    assert eng.cfg.dot_mode == "int8"
+    assert eng.cfg.dot_plan == SubstratePlan.uniform("int8")
     assert eng.bundle.substrate is sub.get_substrate("int8")
     out = eng.generate([Request(prompt=[1, 2, 3], max_tokens=4)])
     assert len(out[0].output) == 4
@@ -551,7 +551,7 @@ def test_serving_engine_accepts_registry_instance_rejects_custom():
     # a registry-produced instance is accepted and resolves to its spec
     eng = ServingEngine(bundle, params, batch_size=1, max_len=16,
                         substrate=sub.get_substrate("approx_lut"))
-    assert eng.cfg.dot_mode == "approx_lut:proposed"
+    assert eng.cfg.dot_plan == SubstratePlan.uniform("approx_lut:proposed")
 
     # a custom (unregistered) subclass would be silently swapped out by the
     # spec-string model path, so the engine must refuse it

@@ -1,7 +1,6 @@
 """ProductSubstrate registry: cross-backend parity, batched conv/edge
 detection against the single-image loop, and end-to-end model dispatch
 (including the Pallas kernel in interpret mode)."""
-import dataclasses
 import warnings
 
 import jax
@@ -91,14 +90,14 @@ def test_pallas_lut_bitexact_parity(mkn):
     m, k, n = mkn
     a8 = RNG.integers(-128, 128, (m, k)).astype(np.int8)
     b8 = RNG.integers(-128, 128, (k, n)).astype(np.int8)
-    outs = {name: np.asarray(sub.get_substrate(name).dot_int8(a8, b8))
+    outs = {name: np.asarray(sub.get_substrate(name).dot_int(a8, b8))
             for name in ("approx_bitexact", "approx_lut", "approx_pallas")}
     np.testing.assert_array_equal(outs["approx_bitexact"], outs["approx_lut"])
     np.testing.assert_array_equal(outs["approx_bitexact"], outs["approx_pallas"])
 
 
 def test_scalar_faithful_dot_matches_scalar_sum():
-    """dot_int8 == Σ_k scalar(a_k, b_k) for every scalar-faithful substrate."""
+    """dot_int == Σ_k scalar(a_k, b_k) for every scalar-faithful substrate."""
     a8 = RNG.integers(-128, 128, (4, 11)).astype(np.int64)
     b8 = RNG.integers(-128, 128, (11, 3)).astype(np.int64)
     for spec in sub.list_substrates():
@@ -108,7 +107,7 @@ def test_scalar_faithful_dot_matches_scalar_sum():
         oracle = np.asarray(
             s.scalar(jnp.asarray(a8[:, :, None], jnp.int32),
                      jnp.asarray(b8[None, :, :], jnp.int32))).sum(axis=1)
-        got = np.asarray(s.dot_int8(a8.astype(np.int8), b8.astype(np.int8)))
+        got = np.asarray(s.dot_int(a8.astype(np.int8), b8.astype(np.int8)))
         np.testing.assert_array_equal(got, oracle, err_msg=spec)
 
 
@@ -190,14 +189,14 @@ def _tiny_cfg(**overrides):
 
 
 def test_bundle_resolves_substrate_once():
-    bundle = reg.build_bundle(_tiny_cfg(dot_mode="approx_lut:design_du2022"))
+    bundle = reg.build_bundle(_tiny_cfg(dot_plan="approx_lut:design_du2022"))
     assert bundle.substrate is sub.get_substrate("approx_lut:design_du2022")
     assert bundle.substrate.meta.mult_name == "design_du2022"
 
 
 def test_model_smoke_approx_pallas_end_to_end():
-    """approx_pallas selectable via cfg.dot_mode (interpret mode on CPU)."""
-    cfg = _tiny_cfg(dot_mode="approx_pallas")
+    """approx_pallas selectable via cfg.dot_plan (interpret mode on CPU)."""
+    cfg = _tiny_cfg(dot_plan="approx_pallas")
     bundle = reg.build_bundle(cfg)
     assert bundle.substrate.meta.name == "approx_pallas"
     params = bundle.init_params(jax.random.PRNGKey(0))
@@ -207,9 +206,3 @@ def test_model_smoke_approx_pallas_end_to_end():
     assert logits.shape == (2, 1, cfg.vocab)
     assert bool(jnp.all(jnp.isfinite(logits.astype(jnp.float32))))
 
-
-def test_edge_detect_config_uses_parameterized_spec():
-    cfg = reg.get_config("edge-detect")
-    name, mult_name, width = sub.parse_spec(cfg.dot_mode)
-    assert name == "approx_bitexact" and mult_name == "proposed" and width == 8
-    assert reg.build_bundle(dataclasses.replace(cfg)).substrate.meta.bit_exact

@@ -180,8 +180,8 @@ def test_substrate_lut_equals_bitexact_width4_on_arbitrary_ints():
     """Wrap semantics: parity must hold even for out-of-4-bit-range int8."""
     a8 = RNG.integers(-128, 128, (6, 13)).astype(np.int8)
     b8 = RNG.integers(-128, 128, (13, 4)).astype(np.int8)
-    bx = np.asarray(sub.get_substrate("approx_bitexact:proposed@4").dot_int8(a8, b8))
-    lt = np.asarray(sub.get_substrate("approx_lut:proposed@4").dot_int8(a8, b8))
+    bx = np.asarray(sub.get_substrate("approx_bitexact:proposed@4").dot_int(a8, b8))
+    lt = np.asarray(sub.get_substrate("approx_lut:proposed@4").dot_int(a8, b8))
     np.testing.assert_array_equal(bx, lt)
 
 
@@ -190,7 +190,7 @@ def test_stat_substrate_wraps_operands_at_narrow_width():
     own scalar model (a K=1 contraction and the scalar agree exactly)."""
     s = sub.get_substrate("approx_stat:proposed@4")
     for a, b in [(8, 3), (-9, 5), (200, -1), (7, 7)]:
-        got = int(s.dot_int8(np.array([[a]], np.int16),
+        got = int(s.dot_int(np.array([[a]], np.int16),
                              np.array([[b]], np.int16))[0, 0])
         want = int(s.scalar(jnp.asarray(a), jnp.asarray(b)))
         assert got == want, (a, b)
@@ -205,7 +205,7 @@ def test_substrate_dot_width16_matches_scalar_sum_mod32():
                  jnp.asarray(b[None, :, :], jnp.int32)),
         dtype=np.int64).sum(axis=1)
     oracle = ((oracle + 2**31) % 2**32 - 2**31).astype(np.int32)  # int32 ring
-    got = np.asarray(s.dot_int8(a.astype(np.int16), b.astype(np.int16)))
+    got = np.asarray(s.dot_int(a.astype(np.int16), b.astype(np.int16)))
     np.testing.assert_array_equal(got, oracle)
 
 
